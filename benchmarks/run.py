@@ -15,6 +15,8 @@ import traceback
 
 import jax
 
+from repro.launch.compile_cache import enable_compile_cache
+
 jax.config.update("jax_enable_x64", True)   # paper CPU baselines are f64
 
 
@@ -30,6 +32,7 @@ def main() -> None:
     ap.add_argument("--json", default=None, metavar="PATH",
                     help="also write rows + failure count as JSON")
     args = ap.parse_args()
+    enable_compile_cache()
     only = set(args.only.split(",")) if args.only else None
 
     print("name,us_per_call,derived")

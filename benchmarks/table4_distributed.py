@@ -1,34 +1,38 @@
 """Paper Table 4: distributed CG throughput/memory under a fixed iteration
 budget (the paper runs 1000 Jacobi-CG iterations at 1e8–4e8 DOF on H200s;
-here: 8 forced host devices, CPU-scaled DOF, 200-iteration budget).
+here: every device of the process, CPU-scaled DOF, 200-iteration budget).
 
 Reports time, per-shard memory estimate, residual-after-budget — plus the
 pipelined-CG variant (beyond-paper: one fused reduction/iteration) and the
 halo-byte count per iteration.  PR 3 adds the plan-engine columns: analyze
 count and setup reuse across a 3-solve tolerance sweep (``PLAN_STATS``) and
 the setup-amortization ratio (first solve incl. analyze+setup vs steady-state
-re-solve on the cached plan).  Runs in a subprocess so the parent keeps its
-single-device view."""
-import os
-import subprocess
-import sys
-import textwrap
+re-solve on the cached plan).
 
-REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+Runs in-process over ``jax.devices()``: on a host without several
+accelerators, give the CPU backend virtual devices on the command line::
 
-SRC = textwrap.dedent("""
-    import time
-    import jax, numpy as np, jax.numpy as jnp
-    jax.config.update("jax_enable_x64", True)
-    from repro.core import PLAN_STATS, reset_plan_stats
-    from repro.core.distributed import DSparseTensor
-    from repro.data.poisson import poisson2d
+    XLA_FLAGS=--xla_force_host_platform_device_count=8 \
+        PYTHONPATH=src python -m benchmarks.run --only table4 --smoke
+"""
+import time
 
-    SMOKE = bool(int("%(smoke)d"))
-    grids = (48, 96) if SMOKE else (64, 128, 256)
-    budget = 100 if SMOKE else 200
+import jax
+import jax.numpy as jnp
+import numpy as np
 
-    mesh = jax.make_mesh((8,), ("data",))
+from repro.core import PLAN_STATS, reset_plan_stats
+from repro.core.distributed import DSparseTensor
+from repro.data.poisson import poisson2d
+
+
+def run(full: bool = False, smoke: bool = False):
+    rows = []
+    grids = (48, 96) if smoke else (64, 128, 256)
+    budget = 100 if smoke else 200
+
+    p = jax.device_count()
+    mesh = jax.make_mesh((p,), ("data",))
     for ng in grids:
         n = ng * ng
         A = poisson2d(ng, dtype=np.float64)
@@ -51,10 +55,10 @@ SRC = textwrap.dedent("""
             shard_mem = (D.meta.nnz_loc * 16 + 6 * D.meta.n_loc * 8)
             halo = (D.meta.h_lo + D.meta.h_hi) * 8
             tag = "pipelined" if pipelined else "standard"
-            print(f"ROW,table4/{tag}/dof={n},{dt/budget*1e6:.1f},"
-                  f"residual_after_budget={res:.1e};"
-                  f"mem_per_shard={shard_mem/2**20:.2f}MiB;"
-                  f"halo_bytes_per_iter={halo};dof_per_s={n*budget/dt:.2e}")
+            rows.append(f"table4/{tag}/dof={n},{dt/budget*1e6:.1f},"
+                        f"residual_after_budget={res:.1e};"
+                        f"mem_per_shard={shard_mem/2**20:.2f}MiB;"
+                        f"halo_bytes_per_iter={halo};dof_per_s={n*budget/dt:.2e}")
 
         # Schwarz ladder (PR 4): one-level vs two-level (deflated coarse
         # correction on cached direct factors) — iterations + per-solve time
@@ -70,8 +74,8 @@ SRC = textwrap.dedent("""
             x, info = solve(D.lval, bsz)
             jax.block_until_ready(x)
             dt = time.perf_counter() - t0
-            print(f"ROW,table4/{pname}/dof={n},{dt*1e6:.1f},"
-                  f"iters={int(info.iters)};converged={bool(info.converged)}")
+            rows.append(f"table4/{pname}/dof={n},{dt*1e6:.1f},"
+                        f"iters={int(info.iters)};converged={bool(info.converged)}")
 
         # plan-engine amortization: cold first solve (analyze + setup) vs
         # steady-state re-solves on the cached plan, counters proving the
@@ -86,29 +90,15 @@ SRC = textwrap.dedent("""
         for tol in (1e-6, 1e-8):
             jax.block_until_ready(Dp.solve(b, tol=tol, maxiter=budget))
         t_steady = (time.perf_counter() - t0) / 2
-        print(f"ROW,table4/plan/dof={n},{t_steady*1e6:.1f},"
-              f"analyze={PLAN_STATS['analyze']};"
-              f"setup_reuse={PLAN_STATS['setup_reuse']};"
-              f"cache_hit={PLAN_STATS['cache_hit']};"
-              f"amortization=x{t_first/max(t_steady,1e-9):.1f};"
-              f"t_first_us={t_first*1e6:.1f}")
-""")
-
-
-def run(full: bool = False, smoke: bool = False):
-    env = dict(os.environ,
-               XLA_FLAGS="--xla_force_host_platform_device_count=8",
-               PYTHONPATH=os.path.join(REPO, "src"))
-    src = SRC % {"smoke": 1 if smoke else 0}
-    proc = subprocess.run([sys.executable, "-c", src], capture_output=True,
-                          text=True, env=env, timeout=1200)
-    if proc.returncode != 0:
-        # raise so benchmarks.run counts the suite as failed and exits
-        # nonzero — the bench-smoke CI gate must go red, not print a row
-        raise RuntimeError(f"table4 subprocess failed: {proc.stderr[-300:]}")
-    return [line[4:] for line in proc.stdout.splitlines()
-            if line.startswith("ROW,")]
+        rows.append(f"table4/plan/dof={n},{t_steady*1e6:.1f},"
+                    f"analyze={PLAN_STATS['analyze']};"
+                    f"setup_reuse={PLAN_STATS['setup_reuse']};"
+                    f"cache_hit={PLAN_STATS['cache_hit']};"
+                    f"amortization=x{t_first/max(t_steady,1e-9):.1f};"
+                    f"t_first_us={t_first*1e6:.1f}")
+    return rows
 
 
 if __name__ == "__main__":
+    import sys
     print("\n".join(run(smoke="--smoke" in sys.argv)))

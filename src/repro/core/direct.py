@@ -95,6 +95,20 @@ __all__ = [
 ]
 
 SN_MAX_W = 32            # supernode width cap (panel column count per bucket)
+# lanes per panel launch where the Pallas panel kernels run: a wider bucket
+# runs as several launches of one program (same-level lanes are
+# independent).  The TPU compile time of a bucket program grows faster than
+# its lane count (v5e, 4x32 panels: ~1.5 s at 256 lanes, ~55 s at 4096), and
+# the launches stay few.  The jnp oracles (CPU) take no cap: there each
+# launch costs dispatch time (1e4-DOF Poisson: 195 launches at 128 lanes
+# against 145, factorize 17% slower).
+SN_MAX_LANES = 128
+
+
+def _lane_cap() -> Optional[int]:
+    """Lanes per panel launch: ``SN_MAX_LANES`` for the Pallas kernels,
+    uncapped for the jnp oracles."""
+    return SN_MAX_LANES if _sn_use_pallas() else None
 
 
 class PackedFactor(NamedTuple):
@@ -849,12 +863,14 @@ def _emit_snode(n: int, nnzL: int, Li: np.ndarray, Lptr: np.ndarray,
     Supernodes are scheduled by assembly-tree level (longest path over
     cross-supernode L edges — every edge source has the smaller supernode id,
     so one ascending pass computes levels), then bucketed by padded panel
-    shape (pow2 width/sub-row counts, pow2 lane counts) so the number of
-    distinct compiled panel kernels is logarithmic in problem size.
+    shape (pow2 width/sub-row counts, pow2 lane counts, at most
+    ``SN_MAX_LANES`` lanes per Pallas launch) so the number of distinct
+    compiled panel kernels is logarithmic in problem size.
     """
     ns = sptr.size - 1
     if ns == 0:
         return None
+    cap = _lane_cap()
     c0 = sptr[:-1]
     c1 = sptr[1:]
     w = c1 - c0
@@ -909,6 +925,8 @@ def _emit_snode(n: int, nnzL: int, Li: np.ndarray, Lptr: np.ndarray,
         idx = np.asarray(members, dtype=np.int64)
         k = idx.size
         kp = 1 << max(int(k - 1).bit_length(), 0)   # pow2 lanes, pads no-op
+        if cap is not None and kp > cap:           # whole launches
+            kp = -(-k // cap) * cap
         c0g = c0[idx]
         wg = w[idx]
         rg = r[idx]
@@ -919,7 +937,8 @@ def _emit_snode(n: int, nnzL: int, Li: np.ndarray, Lptr: np.ndarray,
         rows_blk = np.where(tw, c0g[:, None] + aw[None, :], n)
         pstart = Lptr[c1[idx] - 1]
         gidx = np.minimum(pstart[:, None] + ar[None, :], max(nnzL - 1, 0))
-        rows_sub = np.where(ta, Li[gidx], n)
+        # a diagonal matrix has an empty L: every r is 0, nothing to gather
+        rows_sub = np.where(ta, Li[gidx], n) if nnzL else np.full_like(gidx, n)
         rows = np.concatenate([rows_blk, rows_sub], axis=1)   # (k, wb+rb)
         cjs = c0g[:, None] + aw[None, :]                      # (k, wb)
         vP = (rows < n)[:, :, None] & tw[:, None, :]
@@ -954,14 +973,17 @@ def _emit_snode(n: int, nnzL: int, Li: np.ndarray, Lptr: np.ndarray,
             wg = lanepad(wg, 0)
             rg = lanepad(rg, 0)
             bkm = lanepad(bkm, False)
-        by_level[lv].append(SnodeBucket(
-            wb=int(wb), rb=int(rb), pairs=bool(want_pairs and bkm.any()),
-            pidx=jnp.asarray(pidx), qidx=jnp.asarray(qidx),
-            uidx=jnp.asarray(uidx),
-            rows=jnp.asarray(rows.astype(np.int32)),
-            wvec=jnp.asarray(wg.astype(np.int32)),
-            rvec=jnp.asarray(rg.astype(np.int32)),
-            bkm=jnp.asarray(bkm)))
+        for s in range(0, kp, cap or kp):
+            sl = slice(s, s + (cap or kp))
+            by_level[lv].append(SnodeBucket(
+                wb=int(wb), rb=int(rb),
+                pairs=bool(want_pairs and bkm[sl].any()),
+                pidx=jnp.asarray(pidx[sl]), qidx=jnp.asarray(qidx[sl]),
+                uidx=jnp.asarray(uidx[sl]),
+                rows=jnp.asarray(rows[sl].astype(np.int32)),
+                wvec=jnp.asarray(wg[sl].astype(np.int32)),
+                rvec=jnp.asarray(rg[sl].astype(np.int32)),
+                bkm=jnp.asarray(bkm[sl])))
 
     if pair_p1:
         p1 = np.sort(np.concatenate(pair_p1))
@@ -1255,6 +1277,28 @@ def schwarz_numeric(sch: SchwarzArtifacts, flat_val: jax.Array) -> jax.Array:
 # supernodal numeric drivers (per-bucket compiled panel kernels)
 # ---------------------------------------------------------------------------
 
+# XLA:TPU compiles a scatter into an operand of 16 MiB or more on a slow path
+# (~9 s a program on v5e, against ~0.3 s below it), and the factor storage of
+# a 1e5-DOF problem is larger; it is scattered in pieces of 2^21 entries.
+_SCATTER_PIECE = 1 << 21
+
+
+def _scatter(C: jax.Array, idx: jax.Array, v: jax.Array,
+             add: bool = False) -> jax.Array:
+    """``C.at[idx].set(v)`` (or ``.add``), piece by piece over ``C``."""
+    m = C.shape[0]
+    if m <= _SCATTER_PIECE:
+        return C.at[idx].add(v) if add else C.at[idx].set(v)
+    out = []
+    for off in range(0, m, _SCATTER_PIECE):
+        size = min(_SCATTER_PIECE, m - off)
+        # indices outside this piece point past its end and are dropped
+        q = jnp.where((idx >= off) & (idx < off + size), idx - off, size)
+        at = C[off:off + size].at[q]
+        out.append(at.add(v, mode="drop") if add else at.set(v, mode="drop"))
+    return jnp.concatenate(out)
+
+
 def _sn_use_pallas() -> bool:
     """Pallas panel kernels on compiled backends; jnp oracles on CPU (the
     same routing as the fused solver steps — interpret-mode emulation would
@@ -1289,9 +1333,9 @@ def _sn_factor_fn(wb: int, rb: int, pairs: bool, guard: bool,
         # pad slots all point at the scratch sink; colliding pad writes are
         # masked zeros/ones and every later gather re-masks, so the sink's
         # value is never observed
-        C = C.at[pidx].set(P)
-        C = C.at[qidx].set(Q)
-        C = C.at[uidx].add(-S)
+        C = _scatter(C, pidx, P)
+        C = _scatter(C, qidx, Q)
+        C = _scatter(C, uidx, -S, add=True)
         return C, nbad
 
     return fn
@@ -1309,6 +1353,10 @@ def _sn_sweep_fn(wb: int, rb: int, pairs: bool, mode: str, use_pallas: bool):
     """
     from ..kernels import ref as _kref
     from ..kernels import supernode as _ksn
+
+    # the panel GEMVs carry the factor's accuracy: full f32 products (a
+    # TPU f32 matmul otherwise takes one bf16 pass)
+    _HI = jax.lax.Precision.HIGHEST
 
     def trsv(D, yb, wvec, bkm):
         if use_pallas:
@@ -1329,20 +1377,22 @@ def _sn_sweep_fn(wb: int, rb: int, pairs: bool, mode: str, use_pallas: bool):
             Qm = jnp.where(tw[:, :, None] & ta[:, None, :], C[qidx], 0.0)
         if mode == "l":
             yb = trsv(D, y[wb_rows], wvec, bkm)
-            upd = jnp.einsum("kaw,kw->ka", Pp, yb)
+            upd = jnp.einsum("kaw,kw->ka", Pp, yb, precision=_HI)
             y = y.at[wb_rows].set(jnp.where(tw, yb, 0.0))
             return y.at[rb_rows].add(-upd)
         if mode == "u":
-            xb0 = y[wb_rows] - jnp.einsum("ktr,kr->kt", Qm, y[rb_rows])
+            xb0 = y[wb_rows] - jnp.einsum("ktr,kr->kt", Qm, y[rb_rows],
+                                          precision=_HI)
             xb = trsv(D, xb0, wvec, bkm)
             return y.at[wb_rows].set(jnp.where(tw, xb, 0.0))
         if mode == "ut":
             yb = trsv(D, y[wb_rows], wvec, bkm)
-            upd = jnp.einsum("ktr,kt->kr", Qm, yb)
+            upd = jnp.einsum("ktr,kt->kr", Qm, yb, precision=_HI)
             y = y.at[wb_rows].set(jnp.where(tw, yb, 0.0))
             return y.at[rb_rows].add(-upd)
         # mode == "lt"
-        xb0 = y[wb_rows] - jnp.einsum("kaw,ka->kw", Pp, y[rb_rows])
+        xb0 = y[wb_rows] - jnp.einsum("kaw,ka->kw", Pp, y[rb_rows],
+                                      precision=_HI)
         xb = trsv(D, xb0, wvec, bkm)
         return y.at[wb_rows].set(jnp.where(tw, xb, 0.0))
 
@@ -1468,8 +1518,10 @@ def numeric_factor(art: DirectArtifacts, val: jax.Array, *,
         pivot_eps if pivot_eps is not None
         else jnp.sqrt(jnp.finfo(val.dtype).eps) * jnp.maximum(scale, 1e-300),
         val.dtype)
-    C = jnp.zeros(art.nnzF + 2, dtype=val.dtype)
-    C = C.at[art.a2f].add(val).at[art.nnzF + 1].set(1.0)
+    # the last slot is the scratch 1.0 pads divide by; a2f never points there
+    C = jnp.concatenate([jnp.zeros(art.nnzF + 1, val.dtype),
+                         jnp.ones(1, val.dtype)])
+    C = _scatter(C, art.a2f, val, add=True)
 
     if art.snode is not None:
         C, nbad = _snode_numeric(art, C, tau, pivot_guard)

@@ -74,7 +74,8 @@ from . import direct as _direct
 from . import options as _options
 from . import precond as _precond
 from . import solvers as _solvers
-from .sparse import SparseTensor, build_bell, coo_matvec, has_full_diagonal
+from .sparse import (SparseTensor, backward_error, build_bell, coo_matvec,
+                     has_full_diagonal)
 
 # The dispatch knobs (dense/direct budgets, BELL fill floor, fused-step mode,
 # plan-cache bounds) live in repro.core.options now — one immutable record
@@ -393,6 +394,11 @@ class Backend:
     def analyze(self, cfg: SolverConfig, pattern) -> dict:
         return {}
 
+    def pallas_kernels(self, plan: "SolverPlan") -> Tuple[str, ...]:
+        """The compiled Pallas kernels this plan launches (none by default);
+        setup checks the values' dtype against them."""
+        return ()
+
     def setup(self, plan: "SolverPlan", A: SparseTensor):
         return None
 
@@ -463,6 +469,12 @@ class DirectBackend(Backend):
                           else None))
         return {"direct": art, "transposed": False}
 
+    def pallas_kernels(self, plan):
+        if plan.artifacts["direct"].snode is None or \
+                not _direct._sn_use_pallas():
+            return ()
+        return ("panel_factor", "schur_update", "block_trsv")
+
     def setup(self, plan, A):
         PLAN_STATS["factorize"] += 1
         return _direct.numeric_factor(plan.artifacts["direct"], A.val)
@@ -471,10 +483,13 @@ class DirectBackend(Backend):
         x = _direct.factored_solve(plan.artifacts["direct"], C, b,
                                    transposed=plan.artifacts["transposed"])
         r = b - coo_matvec(A.val, A.row, A.col, x, A.shape[0])
-        rn = jnp.linalg.norm(r)
-        target = jnp.maximum(cfg.tol * jnp.linalg.norm(b), cfg.atol)
-        return x, _solvers.SolveInfo(iters=jnp.asarray(1), resnorm=rn,
-                                     converged=rn <= target)
+        # a direct solve is judged by its normwise backward error, which
+        # f32 reaches at any size; ||r||/||b|| stalls near eps·||A||·||x||
+        berr = backward_error(A.val, A.row, A.col, A.shape[0], x, b, r=r)
+        converged = (berr <= cfg.tol) | (jnp.max(jnp.abs(r)) <= cfg.atol)
+        return x, _solvers.SolveInfo(iters=jnp.asarray(1),
+                                     resnorm=jnp.linalg.norm(r),
+                                     converged=converged)
 
     def transpose_plan(self, plan):
         """Adjoint plan sharing THIS plan's symbolic artifacts and numeric
@@ -514,6 +529,15 @@ class IterativeBackend(Backend):
             "precond": _precond.PreconditionerPlan(
                 cfg.precond, pattern.row, pattern.col, pattern.shape,
                 stencil=pattern.stencil)}
+
+    def pallas_kernels(self, plan):
+        kp = plan.artifacts.get("kernel")
+        names = ()
+        if kp is not None and kp.choice != "coo" and not kp.interpret:
+            names += (kp.choice,)
+        if _fuse_enabled(kp) and plan.cfg.method in ("cg", "bicgstab"):
+            names += ("fused step",)
+        return names
 
     def _matvec_from_val(self, plan, val) -> Callable:
         kp = plan.artifacts.get("kernel")
@@ -918,6 +942,14 @@ class SolverPlan:
         box["entry"] = (weakref.ref(key_array, _drop), state)
         memo[slot] = box["entry"]
 
+    def check_dtype(self, dtype) -> None:
+        """Refuse values the plan's compiled Pallas kernels cannot take
+        (f64 on TPU), naming the dtype and the kernels."""
+        from ..kernels.solve_step import check_kernel_dtype, default_interpret
+        kernels = self.backend.pallas_kernels(self)
+        if kernels:
+            check_kernel_dtype(", ".join(kernels), dtype, default_interpret())
+
     def setup(self, A: SparseTensor):
         """Run (or reuse) the backend's values-dependent setup.
 
@@ -939,6 +971,7 @@ class SolverPlan:
             hit = self._memo_lookup("state", A.val)
             if hit is not None:
                 return hit
+        self.check_dtype(A.val.dtype)
         PLAN_STATS["setup"] += 1
         state = self.backend.setup(self, A)
         if self.backend.cache_setup:
@@ -961,6 +994,7 @@ class SolverPlan:
             hit = self._memo_lookup("batch_state", val)
             if hit is not None:
                 return hit
+        self.check_dtype(val.dtype)
         PLAN_STATS["setup"] += 1
         flat = val.reshape((-1, val.shape[-1]))
         state = jax.vmap(

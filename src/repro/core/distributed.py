@@ -34,14 +34,13 @@ from __future__ import annotations
 import dataclasses
 import functools
 from functools import partial
-from typing import Callable, Optional, Tuple
+from typing import Callable, NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 from jax import lax
-from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
-from jax.experimental.shard_map import shard_map
+from jax.sharding import AxisType, Mesh, NamedSharding, PartitionSpec as P
 
 from . import dispatch as _dispatch
 from . import solvers as _solvers
@@ -144,15 +143,10 @@ halo_apply.defvjp(_halo_apply_fwd, _halo_apply_bwd)
 
 
 def halo_exchange(x: jax.Array, h_lo: int, h_hi: int, axis: str) -> jax.Array:
-    """Legacy entry point: derive the program from the ambient mesh axis.
-
-    ``lax.psum`` of a static ``1`` folds to a concrete axis size at trace
-    time, so this works on any jax that has shard_map (``lax.axis_size``
-    does not exist on older releases).  Prefer :func:`halo_apply` with a
-    plan-cached :func:`halo_program` on hot paths.
-    """
-    p = lax.psum(1, axis)
-    return halo_apply(halo_program(h_lo, h_hi, axis, int(p)), x)
+    """H with the program derived from the ambient mesh axis (inside
+    ``shard_map``).  Prefer :func:`halo_apply` with a plan-cached
+    :func:`halo_program` on hot paths."""
+    return halo_apply(halo_program(h_lo, h_hi, axis, lax.axis_size(axis)), x)
 
 
 # ---------------------------------------------------------------------------
@@ -225,6 +219,20 @@ def _partition_pattern(row: np.ndarray, col: np.ndarray, bounds: np.ndarray):
 # DSparseTensor
 # ---------------------------------------------------------------------------
 
+def auto_mesh(mesh: Mesh) -> Mesh:
+    """The same devices and axis names with every axis ``Auto``.
+
+    ``jax.make_mesh`` gives ``Explicit`` axes, under which an array's
+    sharding is part of its type: a vmap or concatenate that mixes the
+    sharded stacked values with replicated analyze-time tables is refused.
+    The distributed layer shards only through ``NamedSharding`` and
+    ``shard_map``, which is what ``Auto`` axes mean."""
+    if all(t == AxisType.Auto for t in mesh.axis_types):
+        return mesh
+    return Mesh(mesh.devices, mesh.axis_names,
+                axis_types=(AxisType.Auto,) * len(mesh.axis_names))
+
+
 @dataclasses.dataclass(frozen=True)
 class DistMeta:
     n: int
@@ -262,7 +270,7 @@ class DSparseTensor:
         # legacy slots: the Aᵀ partition is a PLAN artifact now (built once
         # per pattern by analyze); kept only for constructor/pytree compat
         self.lval_t, self.lrow_t, self.lcol_t = lval_t, lrow_t, lcol_t
-        self.mesh = mesh
+        self.mesh = auto_mesh(mesh)
         from .sparse import _plan_cache
         self._plans = _plan_cache()
 
@@ -349,6 +357,7 @@ class DSparseTensor:
         """Partition a global COO matrix across ``mesh[axis]`` (eager)."""
         val = np.asarray(val); row = np.asarray(row); col = np.asarray(col)
         n = shape[0]
+        mesh = auto_mesh(mesh)
         p = mesh.shape[axis]
         if symmetric is None:
             from .sparse import detect_properties
@@ -414,9 +423,9 @@ class DSparseTensor:
         prog = self._halo()
         spec = P(m.axis)
 
-        @partial(shard_map, mesh=self.mesh,
+        @partial(jax.shard_map, mesh=self.mesh,
                  in_specs=(spec, spec, spec, spec), out_specs=spec,
-                 check_rep=False)
+                 check_vma=False)
         def run(lval, lrow, lcol, x):
             y = _local_matvec(prog, m.n_loc, lval[0], lrow[0], lcol[0], x[0],
                               differentiable=True)
@@ -465,9 +474,9 @@ class DSparseTensor:
         spec = P(m.axis)
 
         def impl(lval):
-            @partial(shard_map, mesh=self.mesh,
+            @partial(jax.shard_map, mesh=self.mesh,
                      in_specs=(spec, spec, spec), out_specs=(P(None), spec),
-                     check_rep=False)
+                     check_vma=False)
             def run(lval, lrow, lcol):
                 lv, lr, lc = lval[0], lrow[0], lcol[0]
                 mv = lambda x: _local_matvec(prog, m.n_loc, lv, lr, lc, x)
@@ -495,9 +504,9 @@ class DSparseTensor:
                          # eigenvalue grads; vector grads are a single-device
                          # feature here)
 
-            @partial(shard_map, mesh=self.mesh,
+            @partial(jax.shard_map, mesh=self.mesh,
                      in_specs=(P(None), spec, spec, spec), out_specs=spec,
-                     check_rep=False)
+                     check_vma=False)
             def assemble(gw, V, lrow, lcol):
                 Vq = V[0]                      # (n_loc, k)
                 Vx = jnp.swapaxes(Vq, 0, 1)    # (k, n_loc)
@@ -565,6 +574,62 @@ def _local_matvec(prog: HaloProgram, n_loc: int, lv, lr, lc, x,
     return jax.ops.segment_sum(lv * x_ext[lc], lr, num_segments=n_loc)
 
 
+class EllLayout(NamedTuple):
+    """Row-slot (ELL) layout of the stacked local pattern (analyze-time
+    artifact), the one table the Krylov loop's SpMV and the jacobi refresh
+    read.
+
+    ``src[q, k, r]`` is the local value slot of row ``r``'s ``k``-th entry on
+    shard ``q`` (``nnz_loc``, which reads as a zero, where the row has fewer
+    than ``k + 1``) and ``col[q, k, r]`` its index into the halo'd x.  The
+    SpMV is then a gather and a sum over ``k``, with no scatter: on one TPU
+    v5e at 2^22 rows and 2^24 entries the gather-plus-``segment_sum``
+    product took 413 ms a call, this gather 155 ms.  Storage is the longest
+    row times the rows, so a pattern with a few very long rows pays for
+    them on every row."""
+    src: jax.Array                 # (P, k, n_loc) int32, sharded
+    col: jax.Array                 # (P, k, n_loc) int32, sharded
+
+
+def ell_layout(lrow, lcol, meta: DistMeta, mesh: Mesh) -> EllLayout:
+    """The stacked pattern's :class:`EllLayout` (eager, values-free)."""
+    lr = np.asarray(lrow)
+    lc = np.asarray(lcol)
+    p, nnz_loc = lr.shape
+    counts = meta.shard_nnz or (nnz_loc,) * p
+    orders = [np.argsort(lr[q, :counts[q]], kind="stable") for q in range(p)]
+    k = max(max(np.bincount(lr[q, :counts[q]]).max(initial=0)
+                for q in range(p)), 1)
+    src = np.full((p, k, meta.n_loc), nnz_loc, np.int32)
+    col = np.zeros((p, k, meta.n_loc), np.int32)
+    for q, o in enumerate(orders):
+        r = lr[q, o]
+        slot = np.arange(r.size) - np.searchsorted(r, r)   # rank in its row
+        src[q, slot, r] = o
+        col[q, slot, r] = lc[q, o]
+    shard = NamedSharding(mesh, P(meta.axis))
+    return EllLayout(jax.device_put(src, shard), jax.device_put(col, shard))
+
+
+def ell_values(lv, src_q):
+    """One shard's values in row-slot order, ``(k, n_loc)`` (pads zero);
+    gathered once per solve or refresh, outside the Krylov loop."""
+    return jnp.append(lv, jnp.zeros((), lv.dtype))[src_q]
+
+
+def _ell_matvec(prog: HaloProgram, ve, col_q, x):
+    """halo exchange + local SpMV as a row-slot gather — inside shard_map.
+    Slots are added one by one in row order, as a scatter-add would: a
+    ``jnp.sum`` over them rounds differently, and pipelined CG, which
+    carries that rounding through four recurrences, left residuals up to
+    3x larger after a stalled run."""
+    xg = _halo_run(prog, x)[col_q]
+    y = ve[0] * xg[0]
+    for k in range(1, ve.shape[0]):
+        y = y + ve[k] * xg[k]
+    return y
+
+
 def dist_analyze(cfg, plan) -> dict:
     """analyze(pattern): freeze every eager artifact for one
     (global pattern, mesh, partition) — runs once, cached on the plan."""
@@ -572,11 +637,13 @@ def dist_analyze(cfg, plan) -> dict:
     meta = plan.dmeta
     bounds = partition_simple(meta.n, meta.p)
     prog = halo_program(meta.h_lo, meta.h_hi, meta.axis, meta.p)
+    ell = ell_layout(plan.row, plan.col, meta, plan.mesh)
     return {
         "halo": prog,
         "bounds": bounds,
+        "ell": ell,
         "precond": DistPreconditionerPlan(cfg.precond, plan.row, plan.col,
-                                          meta, bounds=bounds),
+                                          meta, bounds=bounds, ell=ell),
         "transposed": False,
         # non-symmetric only: the Aᵀ partition is a plan artifact, built
         # lazily on the FIRST plan.transpose() (forward-only solves never
@@ -607,14 +674,16 @@ def _build_t_partition(cfg, plan, meta: DistMeta, bounds) -> dict:
     shard = NamedSharding(plan.mesh, P(meta.axis))
     dev = lambda a: jax.device_put(jnp.asarray(a), shard)
     lrow_t, lcol_t = dev(lrow_t), dev(lcol_t)
+    ell = ell_layout(lrow_t, lcol_t, t_meta, plan.mesh)
     return {
         "meta": t_meta,
         "lrow": lrow_t,
         "lcol": lcol_t,
         "gather": jnp.asarray(gather),
         "halo": halo_program(h_lo_t, h_hi_t, meta.axis, meta.p),
+        "ell": ell,
         "precond": DistPreconditionerPlan(cfg.precond, lrow_t, lcol_t,
-                                          t_meta, bounds=bounds),
+                                          t_meta, bounds=bounds, ell=ell),
     }
 
 
@@ -646,7 +715,8 @@ def dist_transpose_plan(plan):
     tp._cache = {tp.cfg.plan_key() + (tmeta.axis, tmeta.p, tmeta.n_loc): tp}
     tp._tplan = plan
     tp._setup_memo = {}     # Aᵀ values differ from the forward values
-    tp.artifacts = {"halo": t["halo"], "bounds": plan.artifacts["bounds"],
+    tp.artifacts = {"halo": t["halo"], "ell": t["ell"],
+                    "bounds": plan.artifacts["bounds"],
                     "precond": t["precond"], "transposed": True}
     return tp
 
@@ -690,54 +760,72 @@ def dist_solve(plan, state, A, b, x0, cfg):
     method = cfg.method
     if method not in ("cg", "bicgstab", "pipelined_cg"):
         raise ValueError(f"unknown distributed method {method!r}")
+    stacked = (meta.p, meta.n_loc)
+    for name, v in (("b", b), ("x0", x0)):
+        if v is not None and tuple(v.shape) != stacked:
+            # a global (n,) vector would reach the halo exchange as 0-d
+            # per-shard slices
+            raise ValueError(
+                f"distributed {name} must be stacked (P, n_loc) = {stacked}, "
+                f"got {tuple(v.shape)}; use DSparseTensor.stack_vector")
 
     # state leaves may be stacked-and-sharded (P, ·) or replicated (the
     # two-level Schwarz coarse factor) — the preconditioner plan says which
     sharded = pplan.state_sharded()
-    in_specs = (spec,) * (4 + (1 if have_x0 else 0)) + \
+    ell = plan.artifacts["ell"]
+    in_specs = (spec,) * (4 + (1 if have_x0 else 0)) + (P(),) * 3 + \
         tuple(spec if sh else P() for sh in sharded)
 
-    @partial(shard_map, mesh=plan.mesh, in_specs=in_specs,
-             out_specs=(spec, P()), check_rep=False)
-    def run(lval, lrow, lcol, bq, *rest):
-        x0q = rest[0][0] if have_x0 else None
-        raw = rest[1:] if have_x0 else rest
-        sleaves = tuple(s[0] if sh else s for s, sh in zip(raw, sharded))
-        lv, lr, lc = lval[0], lrow[0], lcol[0]
-        mv = lambda xv: _local_matvec(prog, meta.n_loc, lv, lr, lc, xv)
-        pdot = lambda u, v: lax.psum(jnp.sum(u * v), meta.axis)
-        M = pplan.local_closure(sleaves,
-                                lambda r: _halo_run(prog, r),
-                                lambda z: _halo_run_t(prog, z),
-                                matvec=mv)
-        if method == "pipelined_cg":
-            if x0q is None:
-                x, info = pipelined_cg(mv, bq[0], M=M, tol=cfg.tol,
-                                       atol=cfg.atol, maxiter=cfg.maxiter,
-                                       axis=meta.axis)
+    # one compiled program per (method, warm start) on this plan: run
+    # eagerly, shard_map would compile and dispatch its body op by op.
+    # tol, atol and maxiter are arguments, so a tolerance sweep reuses it
+    programs = plan.artifacts.setdefault("programs", {})
+    key = (method, have_x0)
+    if key not in programs:
+        @partial(jax.shard_map, mesh=plan.mesh, in_specs=in_specs,
+                 out_specs=(spec, P()), check_vma=False)
+        def run(lval, src, col, bq, *rest):
+            x0q = rest[0][0] if have_x0 else None
+            rest = rest[1:] if have_x0 else rest
+            tol, atol, maxiter = rest[:3]
+            sleaves = tuple(s[0] if sh else s
+                            for s, sh in zip(rest[3:], sharded))
+            ve = ell_values(lval[0], src[0])
+            mv = lambda xv: _ell_matvec(prog, ve, col[0], xv)
+            pdot = lambda u, v: lax.psum(jnp.sum(u * v), meta.axis)
+            M = pplan.local_closure(sleaves,
+                                    lambda r: _halo_run(prog, r),
+                                    lambda z: _halo_run_t(prog, z),
+                                    matvec=mv)
+            if method == "pipelined_cg":
+                if x0q is None:
+                    x, info = pipelined_cg(mv, bq[0], M=M, tol=tol,
+                                           atol=atol, maxiter=maxiter,
+                                           axis=meta.axis)
+                else:
+                    # warm start by shift — but keep the convergence
+                    # target relative to the ORIGINAL b, matching the
+                    # cg/bicgstab paths
+                    target = jnp.maximum(
+                        tol * jnp.sqrt(pdot(bq[0], bq[0])), atol)
+                    x, info = pipelined_cg(mv, bq[0] - mv(x0q), M=M,
+                                           tol=jnp.zeros_like(tol),
+                                           atol=target, maxiter=maxiter,
+                                           axis=meta.axis)
+                    x = x + x0q
             else:
-                # warm start by shift — but keep the convergence target
-                # relative to the ORIGINAL b, matching the cg/bicgstab paths
-                target = jnp.maximum(
-                    cfg.tol * jnp.sqrt(pdot(bq[0], bq[0])), cfg.atol)
-                x, info = pipelined_cg(mv, bq[0] - mv(x0q), M=M, tol=0.0,
-                                       atol=target, maxiter=cfg.maxiter,
-                                       axis=meta.axis)
-                x = x + x0q
-        elif method == "cg":
-            x, info = _solvers.cg(mv, bq[0], x0q, M=M, tol=cfg.tol,
-                                  atol=cfg.atol, maxiter=cfg.maxiter,
-                                  dot=pdot)
-        else:
-            x, info = _solvers.bicgstab(mv, bq[0], x0q, M=M, tol=cfg.tol,
-                                        atol=cfg.atol, maxiter=cfg.maxiter,
-                                        dot=pdot)
-        return x[None], info
+                solver = _solvers.cg if method == "cg" else _solvers.bicgstab
+                x, info = solver(mv, bq[0], x0q, M=M, tol=tol, atol=atol,
+                                 maxiter=maxiter, dot=pdot)
+            return x[None], info
 
-    args = (A.lval, plan.row, plan.col, b)
-    if have_x0:
-        args = args + (x0,)
-    return run(*(args + state))
+        programs[key] = jax.jit(run)
+
+    dt = A.lval.dtype
+    args = (A.lval, ell.src, ell.col, b) + ((x0,) if have_x0 else ()) + (
+        jnp.asarray(cfg.tol, dt), jnp.asarray(cfg.atol, dt),
+        jnp.asarray(cfg.maxiter, jnp.int32))
+    return programs[key](*(args + state))
 
 
 def assemble_matrix_grad(plan, lam, x):
@@ -747,8 +835,8 @@ def assemble_matrix_grad(plan, lam, x):
     prog = plan.artifacts["halo"]
     spec = P(meta.axis)
 
-    @partial(shard_map, mesh=plan.mesh, in_specs=(spec, spec, spec, spec),
-             out_specs=spec, check_rep=False)
+    @partial(jax.shard_map, mesh=plan.mesh, in_specs=(spec, spec, spec, spec),
+             out_specs=spec, check_vma=False)
     def assemble(lamq, xq, lrow, lcol):
         x_ext = _halo_run(prog, xq[0])
         gval = -(lamq[0][lrow[0]] * x_ext[lcol[0]])

@@ -315,8 +315,10 @@ class DistPreconditionerPlan:
     build stage only sees the pattern (stacked local row/col indices +
     ``DistMeta``) and precomputes every values-free artifact eagerly:
 
-    * ``jacobi`` — the per-shard diagonal-entry mask (padding excluded via
-      ``meta.shard_nnz``), so ``refresh`` is a single masked ``segment_sum``.
+    * ``jacobi`` — nothing of its own: ``refresh`` reads each row's
+      diagonal entries from the plan's row-slot table
+      (:class:`~repro.core.distributed.EllLayout`, passed as ``ell``), a
+      gather and a sum with no scatter.
     * ``schwarz`` — shard-local overlapping Schwarz: each shard's extended
       matrix ``A[ext, ext]`` (owned rows ∪ halo-overlap rows, Dirichlet
       truncation at the extended boundary — a principal submatrix, so SPD
@@ -346,7 +348,7 @@ class DistPreconditionerPlan:
     """
 
     def __init__(self, name: Optional[str], lrow, lcol, meta, *,
-                 bounds=None, coarsest: int = 160):
+                 bounds=None, ell=None, coarsest: int = 160):
         self.name = "none" if name in (None, "none", "identity") else name
         if self.name not in DIST_PRECONDITIONERS:
             raise ValueError(
@@ -356,14 +358,10 @@ class DistPreconditionerPlan:
         lr = np.asarray(lrow)
         lc = np.asarray(lcol)
         p, nnz_loc = lr.shape
-        valid = np.ones((p, nnz_loc), bool)
-        if meta.shard_nnz is not None:
-            valid = np.arange(nnz_loc)[None, :] < \
-                np.asarray(meta.shard_nnz)[:, None]
         if self.name == "jacobi":
-            self._diag_mask = jnp.asarray(
-                (lr + meta.h_lo == lc) & valid)
-            self._lrow = jnp.asarray(lr, jnp.int32)
+            if ell is None:
+                raise ValueError("jacobi build needs the plan's EllLayout")
+            self._ell = ell
         if self.name in ("schwarz", "schwarz2"):
             from . import direct as _direct
             from .distributed import global_entries
@@ -418,14 +416,15 @@ class DistPreconditionerPlan:
         if self.name == "none":
             return ()
         if self.name == "jacobi":
-            n_loc = self.meta.n_loc
+            from .distributed import ell_values
+            own = jnp.arange(self.meta.n_loc, dtype=jnp.int32) + self.meta.h_lo
 
-            def one(v, m_, r):
-                d = jax.ops.segment_sum(jnp.where(m_, v, 0.0), r,
-                                        num_segments=n_loc)
+            def one(v, src, col):
+                ve = ell_values(v, src)
+                d = jnp.sum(jnp.where(col == own, ve, jnp.zeros_like(ve)), 0)
                 return jnp.where(jnp.abs(d) > 1e-30, 1.0 / d, 1.0)
 
-            return (jax.vmap(one)(lval, self._diag_mask, self._lrow),)
+            return (jax.vmap(one)(lval, self._ell.src, self._ell.col),)
         if self.name in ("schwarz", "schwarz2"):
             from . import direct as _direct
             C = _direct.schwarz_numeric(self._schwarz, lval.reshape(-1))

@@ -43,6 +43,13 @@ class SolveResult(NamedTuple):
     ``reason`` is a static string: ``"converged"``, ``"maxiter"``, or
     ``"unknown"`` when the result is still a tracer (inside jit) and the
     outcome is not concretely decidable.
+
+    ``converged`` means ``‖r‖₂ ≤ max(tol·‖b‖₂, atol)`` for the iterative
+    backends.  A direct solve is judged by its normwise backward error
+    instead: ``‖r‖∞ / (‖A‖∞‖x‖∞ + ‖b‖∞) ≤ tol`` or ``‖r‖∞ ≤ atol``
+    (:func:`repro.core.sparse.backward_error`), which a backward-stable
+    factorization meets in float32 at any size.  ``residual`` is ``‖r‖₂``
+    for every backend.
     """
     x: jax.Array
     iterations: jax.Array

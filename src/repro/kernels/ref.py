@@ -8,6 +8,7 @@ from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 
 def stencil5_ref(val5: jax.Array, x: jax.Array) -> jax.Array:
@@ -103,6 +104,55 @@ def bell_matvec_ref(bell_vals: jax.Array, block_cols: jax.Array,
 # ---------------------------------------------------------------------------
 
 
+def _loop(n: int, body, init):
+    """``fori_loop(0, n, body, init)`` as a scan with an int32 counter.
+
+    ``fori_loop`` with static bounds counts in i64 under x64, which Mosaic
+    cannot lower, and with traced bounds it is not reverse-differentiable."""
+    def step(carry, _):
+        t, x = carry
+        return (t + 1, body(t, x)), None
+    return jax.lax.scan(step, (np.int32(0), init), None, length=n)[0][1]
+
+
+def _mask(keep, X):
+    """X where ``keep``, else 0 — with a zero of X's dtype: a Python float
+    would enter the kernel as an f64 constant under x64."""
+    return jnp.where(keep, X, jnp.zeros_like(X))
+
+
+def _iota(shape, dim):
+    """2-D index grid (Mosaic lowers no 1-D iota)."""
+    return jax.lax.broadcasted_iota(jnp.int32, shape, dim)
+
+
+def _sum11(X):
+    """Sum of a 2-D array kept as a (1, 1) array — one lane reduction then
+    one sublane reduction, both of which Mosaic lowers."""
+    return jnp.sum(jnp.sum(X, axis=1, keepdims=True), axis=0, keepdims=True)
+
+
+def _col(X, cj, t):
+    """Column ``t`` of X as an (m, 1) array: a masked lane reduction, exact
+    (one term survives), in place of a dynamic slice."""
+    return jnp.sum(_mask(cj == t, X), axis=1, keepdims=True)
+
+
+def _row(X, ri, t):
+    """Row ``t`` of X as a (1, n) array (masked sublane reduction)."""
+    return jnp.sum(_mask(ri == t, X), axis=0, keepdims=True)
+
+
+def _pick(v, idx, t):
+    """Entry ``t`` of a (1, n) row or (m, 1) column as a (1, 1) array."""
+    return _sum11(_mask(idx == t, v))
+
+
+def _flag(bk, cr, t):
+    """Pair-start flag of column ``t`` as a (1, 1) bool (False off range)."""
+    return _sum11(((cr == t) & bk).astype(jnp.float32)) > 0
+
+
 def sn_pair_det(a, b, c, e):
     """Clamped determinant of a static Bunch–Kaufman 2x2 pivot E=[[a,b],[c,e]].
 
@@ -123,24 +173,23 @@ def sn_panel_mask(P, Q, w, r):
     """Zero pad rows/cols of a gathered (P, Q) lane; unit pad diagonal."""
     m, wb = P.shape
     rb = Q.shape[1]
-    ri = jnp.arange(m)[:, None]
-    cj = jnp.arange(wb)[None, :]
+    ri = _iota((m, wb), 0)
+    cj = _iota((m, wb), 1)
     tw = cj < w
-    row_ok = jnp.where(ri < wb, ri < w, (ri - wb) < r)
-    P = jnp.where(row_ok & tw, P, 0.0)
-    P = P + jnp.where((ri == cj) & (cj >= w), 1.0, 0.0)
-    Q = jnp.where((jnp.arange(wb)[:, None] < w)
-                  & (jnp.arange(rb)[None, :] < r), Q, 0.0)
+    row_ok = ((ri < wb) & (ri < w)) | ((ri >= wb) & (ri - wb < r))
+    P = _mask(row_ok & tw, P)
+    P = P + ((ri == cj) & (cj >= w)).astype(P.dtype)
+    Q = _mask((_iota((wb, rb), 0) < w) & (_iota((wb, rb), 1) < r), Q)
     return P, Q
 
 
 def sn_block_mask(D, w):
     """Zero pad rows/cols of a gathered diagonal block; unit pad diagonal."""
     wb = D.shape[0]
-    ri = jnp.arange(wb)[:, None]
-    cj = jnp.arange(wb)[None, :]
-    D = jnp.where((ri < w) & (cj < w), D, 0.0)
-    return D + jnp.where((ri == cj) & (ri >= w), 1.0, 0.0)
+    ri = _iota((wb, wb), 0)
+    cj = _iota((wb, wb), 1)
+    D = _mask((ri < w) & (cj < w), D)
+    return D + ((ri == cj) & (ri >= w)).astype(D.dtype)
 
 
 def sn_panel_factor_body(P, Q, w, r, tau, bkm, *, pairs: bool, guard: bool):
@@ -153,23 +202,33 @@ def sn_panel_factor_body(P, Q, w, r, tau, bkm, *, pairs: bool, guard: bool):
     pair is eliminated jointly through E⁻¹ and its four defining entries
     (a, e on the diagonal, b above, c below) are stored raw — they are private
     to the diagonal block, consumed only by block solves and slogdet.
-    Returns (P, Q, nbad) where nbad counts clamped 1x1 pivots / 2x2 dets.
+
+    Written in 2-D masked form (no dynamic slice or 1-D vector) so that the
+    Pallas kernel compiles it as it stands; ``bkm`` may be (wb,) or (1, wb).
+    Returns (P, Q, nbad) where nbad, a (1, 1) array, counts clamped 1x1
+    pivots / 2x2 dets.
     """
     m, wb = P.shape
+    rb = Q.shape[1]
     P, Q = sn_panel_mask(P, Q, w, r)
-    one = jnp.ones((), P.dtype)
-    zero = jnp.zeros((), P.dtype)
-    false = jnp.zeros((), bool)
-    rows = jnp.arange(m)
-    cols = jnp.arange(wb)
+    bk = jnp.reshape(bkm, (1, wb)) != 0
+    ri, cj = _iota((m, wb), 0), _iota((m, wb), 1)
+    rc = _iota((m, 1), 0)
+    cr = _iota((1, wb), 1)
+    qi = _iota((wb, rb), 0)
+    one = jnp.ones((1, 1), P.dtype)
+    zero = jnp.zeros((1, 1), P.dtype)
+    false = zero > 0
 
     def step(t, carry):
         P, Q, nbad = carry
-        start = bkm[t] if pairs else false
-        second = (bkm[jnp.maximum(t - 1, 0)] & (t > 0)) if pairs else false
+        start = _flag(bk, cr, t) if pairs else false
+        second = _flag(bk, cr, t - 1) if pairs else false
         # -- 1x1 elimination (bypassed with a unit divisor on pair members,
         #    so the discarded branch stays finite under AD) --
-        d = P[t, t]
+        Pc = _col(P, cj, t)
+        Pr = _row(P, ri, t)
+        d = _pick(Pr, cr, t)
         if guard:
             bad1 = jnp.abs(d) < tau
             dc = jnp.where(bad1, jnp.where(d < 0, -tau, tau), d)
@@ -177,32 +236,34 @@ def sn_panel_factor_body(P, Q, w, r, tau, bkm, *, pairs: bool, guard: bool):
             bad1 = false
             dc = d
         deff = jnp.where(start | second, one, dc) if pairs else dc
-        colL = jnp.where(rows > t, P[:, t] / deff, 0.0)
-        urow = jnp.where(cols > t, P[t, :], 0.0)
-        P1 = P - colL[:, None] * urow[None, :]
-        P1 = P1.at[:, t].set(jnp.where(rows > t, colL, P[:, t]))
-        P1 = P1.at[t, t].set(dc)
-        Q1 = Q - colL[:wb, None] * Q[t, :][None, :]
+        colL = _mask(rc > t, Pc / deff)
+        urow = _mask(cr > t, Pr)
+        P1 = P - colL * urow
+        P1 = jnp.where(cj == t, jnp.where(ri > t, colL, P), P1)
+        P1 = jnp.where((ri == t) & (cj == t), dc, P1)
+        Qr = _row(Q, qi, t)
+        Q1 = Q - colL[:wb] * Qr
         if not pairs:
             return P1, Q1, nbad + bad1.astype(P.dtype)
         # -- 2x2 elimination for the pair (t, t+1); t1 is clamped so the
         #    branch stays in-bounds when discarded at t = wb-1 --
         t1 = jnp.minimum(t + 1, wb - 1)
-        a, b = P[t, t], P[t, t1]
-        c, e = P[t1, t], P[t1, t1]
+        Pc1, Pr1 = _col(P, cj, t1), _row(P, ri, t1)
+        # E's entries from the rows and column already extracted
+        a, b = d, _pick(Pr, cr, t1)
+        c, e = _pick(Pc, rc, t1), _pick(Pr1, cr, t1)
         detc, bad2 = sn_pair_det(a, b, c, e)
-        below2 = rows > t1
-        u = jnp.where(below2, P[:, t], 0.0)
-        v = jnp.where(below2, P[:, t1], 0.0)
+        below2 = rc > t1
+        u = _mask(below2, Pc)
+        v = _mask(below2, Pc1)
         lu = (u * e - v * c) / detc
         lv = (v * a - u * b) / detc
-        urow1 = jnp.where(cols > t1, P[t, :], 0.0)
-        urow2 = jnp.where(cols > t1, P[t1, :], 0.0)
-        P2 = P - lu[:, None] * urow1[None, :] - lv[:, None] * urow2[None, :]
-        P2 = P2.at[:, t].set(jnp.where(below2, lu, P[:, t]))
-        P2 = P2.at[:, t1].set(jnp.where(below2, lv, P[:, t1]))
-        Q2 = (Q - lu[:wb, None] * Q[t, :][None, :]
-              - lv[:wb, None] * Q[t1, :][None, :])
+        urow1 = _mask(cr > t1, Pr)
+        urow2 = _mask(cr > t1, Pr1)
+        P2 = P - lu * urow1 - lv * urow2
+        P2 = jnp.where(cj == t, jnp.where(ri > t1, lu, P), P2)
+        P2 = jnp.where(cj == t1, jnp.where(ri > t1, lv, P), P2)
+        Q2 = Q - lu[:wb] * Qr - lv[:wb] * _row(Q, qi, t1)
         Pn = jnp.where(start, P2, jnp.where(second, P, P1))
         Qn = jnp.where(start, Q2, jnp.where(second, Q, Q1))
         nbad = nbad + jnp.where(
@@ -210,7 +271,7 @@ def sn_panel_factor_body(P, Q, w, r, tau, bkm, *, pairs: bool, guard: bool):
             jnp.where(second, zero, bad1.astype(P.dtype)))
         return Pn, Qn, nbad
 
-    return jax.lax.fori_loop(0, wb, step, (P, Q, zero))
+    return _loop(wb, step, (P, Q, zero))
 
 
 def sn_trsv_body(D, y, w, bkm, *, mode: str, pairs: bool):
@@ -227,59 +288,80 @@ def sn_trsv_body(D, y, w, bkm, *, mode: str, pairs: bool):
     With ``pairs``, the stored subdiagonal c at a pair start is NOT an L entry
     (the pair's L block is the identity): the unit-triangular modes mask it,
     and the pivot modes solve the 2x2 system E / Eᵀ jointly.
+
+    The right-hand side is held 2-D in the orientation the mode consumes: a
+    (wb, 1) column where a column of D is subtracted ("l", "u"), a (1, wb)
+    row where a row is ("lt", "ut").  ``y`` may come in any shape of wb
+    elements; the result has ``y``'s shape.
     """
     wb = D.shape[0]
     D = sn_block_mask(D, w)
-    idx = jnp.arange(wb)
-    x = jnp.where(idx < w, y, 0.0)
-    one = jnp.ones((), D.dtype)
-    false = jnp.zeros((), bool)
+    ri, cj = _iota((wb, wb), 0), _iota((wb, wb), 1)
+    colwise = mode in ("l", "u")
+    shape = (wb, 1) if colwise else (1, wb)
+    idx = _iota(shape, 0 if colwise else 1)
+    x = _mask(idx < w, jnp.reshape(y, shape))
+    bk = jnp.reshape(bkm, (1, wb)) != 0
+    cr = _iota((1, wb), 1)
+    one = jnp.ones((1, 1), D.dtype)
+    false = one < 0
+
+    def line(t):
+        """Column t of D for column modes, row t for row modes."""
+        return _col(D, cj, t) if colwise else _row(D, ri, t)
+
+    def at(v, t):
+        return _pick(v, idx, t)
+
     if pairs and mode in ("l", "lt"):
         # pair-start subdiagonal holds raw c — identity in the unit factor
-        sub = (idx[:, None] == idx[None, :] + 1) & bkm[None, :]
-        D = jnp.where(sub, 0.0, D)
+        D = _mask(~((ri == cj + 1) & bk), D)
     if mode == "l":
-        return jax.lax.fori_loop(
-            0, wb,
-            lambda t, x: x - jnp.where(idx > t, D[:, t], 0.0) * x[t], x)
+        x = _loop(wb, lambda t, x: x - _mask(idx > t, line(t)) * at(x, t),
+                  x)
+        return jnp.reshape(x, y.shape)
     if mode == "lt":
         def lt_step(i, x):
             t = wb - 1 - i
-            return x - jnp.where(idx < t, D[t, :], 0.0) * x[t]
-        return jax.lax.fori_loop(0, wb, lt_step, x)
+            return x - _mask(idx < t, line(t)) * at(x, t)
+        return jnp.reshape(_loop(wb, lt_step, x), y.shape)
 
     def step(i, x):
         t = (wb - 1 - i) if mode == "u" else i
-        start = bkm[t] if pairs else false
-        second = (bkm[jnp.maximum(t - 1, 0)] & (t > 0)) if pairs else false
-        dd = jnp.where(start | second, one, D[t, t]) if pairs else D[t, t]
-        xt1 = x[t] / dd
-        if mode == "u":
-            prop = jnp.where(idx < t, D[:, t], 0.0)       # U column above t
-        else:
-            prop = jnp.where(idx > t, D[t, :], 0.0)       # Uᵀ: U row past t
-        x1 = (x - prop * xt1).at[t].set(xt1)
+        start = _flag(bk, cr, t) if pairs else false
+        second = _flag(bk, cr, t - 1) if pairs else false
+        ln_t = line(t)
+        dtt = at(ln_t, t)
+        dd = jnp.where(start | second, one, dtt) if pairs else dtt
+        xt1 = at(x, t) / dd
+        # "u": U column above t;  "ut": U row past t
+        prop = _mask((idx < t) if mode == "u" else (idx > t), ln_t)
+        x1 = jnp.where(idx == t, xt1, x - prop * xt1)
         if not pairs:
             return x1
         t1 = jnp.minimum(t + 1, wb - 1)
-        a, b = D[t, t], D[t, t1]
-        c, e = D[t1, t], D[t1, t1]
+        ln_t1 = line(t1)
+        # D[t, t1] and D[t1, t] sit in line t1 / line t by orientation
+        a, e = dtt, at(ln_t1, t1)
+        b, c = (at(ln_t1, t), at(ln_t, t1)) if colwise else \
+            (at(ln_t, t1), at(ln_t1, t))
         detc, _ = sn_pair_det(a, b, c, e)
-        rt, rt1 = x[t], x[t1]
+        rt, rt1 = at(x, t), at(x, t1)
         if mode == "u":           # E [xt, xtt] = [rt, rt1]
             xt = (e * rt - b * rt1) / detc
             xtt = (a * rt1 - c * rt) / detc
-            p1 = jnp.where(idx < t, D[:, t], 0.0)
-            p2 = jnp.where(idx < t, D[:, t1], 0.0)
+            keep = idx < t
         else:                     # Eᵀ [xt, xtt] = [rt, rt1]
             xt = (e * rt - c * rt1) / detc
             xtt = (a * rt1 - b * rt) / detc
-            p1 = jnp.where(idx > t1, D[t, :], 0.0)
-            p2 = jnp.where(idx > t1, D[t1, :], 0.0)
-        x2 = (x - p1 * xt - p2 * xtt).at[t].set(xt).at[t1].set(xtt)
+            keep = idx > t1
+        p1 = _mask(keep, ln_t)
+        p2 = _mask(keep, ln_t1)
+        x2 = jnp.where(idx == t1, xtt,
+                       jnp.where(idx == t, xt, x - p1 * xt - p2 * xtt))
         return jnp.where(start, x2, jnp.where(second, x, x1))
 
-    return jax.lax.fori_loop(0, wb, step, x)
+    return jnp.reshape(_loop(wb, step, x), y.shape)
 
 
 def sn_panel_factor_ref(P, Q, wvec, rvec, tau, bkm, *, pairs=False,

@@ -22,17 +22,32 @@ from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 BM, BN = 8, 128          # f32/f64 min tile; vectors are viewed as (nb, 8, 128)
 BLK = BM * BN
+# Block indices must lower to i32: a bare Python ``0`` in an index map becomes
+# i64 under ``jax_enable_x64``, and Mosaic refuses the kernel.
+I0 = np.int32(0)
 
 
 def default_interpret() -> bool:
     """Interpret (emulate) only off compiled backends — the satellite fix for
     the old ``interpret=True`` default that silently emulated on TPU."""
     return jax.default_backend() not in ("tpu", "gpu")
+
+
+def check_kernel_dtype(kernel: str, dtype, interpret: bool) -> None:
+    """A compiled Pallas TPU kernel takes float32 operands only (Mosaic has
+    no f64 vector or SMEM scalar type); emulation accepts any dtype.  Raises
+    rather than emulate or reroute, so a wrong dtype never hides the chip."""
+    if not interpret and jnp.dtype(dtype) != jnp.float32:
+        raise ValueError(
+            f"Pallas kernel(s) {kernel} cannot take {jnp.dtype(dtype).name} "
+            f"operands when compiled for {jax.default_backend()}; "
+            f"pass float32 values")
 
 
 def _make_kernel(body, n_in: int, n_sc: int, n_out: int, n_dots: int):
@@ -71,22 +86,23 @@ def _run(body, vecs, scalars, n_out: int, n_dots: int, interpret):
         interpret = default_interpret()
     n = vecs[0].shape[0]
     dtype = vecs[0].dtype
+    check_kernel_dtype("fused step", dtype, interpret)
     nb = max(1, -(-n // BLK))
     pad = nb * BLK - n
     vb = [jnp.pad(v, (0, pad)).reshape(nb, BM, BN) for v in vecs]
     n_in, n_sc = len(vecs), len(scalars)
-    vspec = pl.BlockSpec((1, BM, BN), lambda i: (i, 0, 0))
+    vspec = pl.BlockSpec((1, BM, BN), lambda i: (i, I0, I0))
     in_specs = [vspec] * n_in
     args = list(vb)
     if n_sc:
-        in_specs.append(pl.BlockSpec((1, n_sc), lambda i: (0, 0),
+        in_specs.append(pl.BlockSpec((1, n_sc), lambda i: (I0, I0),
                                      memory_space=pltpu.SMEM))
         args.append(jnp.stack([jnp.asarray(s, dtype) for s in scalars])
                     .reshape(1, n_sc))
     out_specs = [vspec] * n_out
     out_shape = [jax.ShapeDtypeStruct((nb, BM, BN), dtype)] * n_out
     if n_dots:
-        out_specs.append(pl.BlockSpec((1, n_dots), lambda i: (0, 0),
+        out_specs.append(pl.BlockSpec((1, n_dots), lambda i: (I0, I0),
                                       memory_space=pltpu.SMEM))
         out_shape.append(jax.ShapeDtypeStruct((1, n_dots), dtype))
     res = pl.pallas_call(

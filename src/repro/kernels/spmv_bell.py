@@ -9,6 +9,11 @@ product is a dense (bm, bn)·(bn,) contraction on VMEM-resident tiles.
 
 Grid: (n_rb, k) — the output band is revisited across the k slot dimension
 and accumulated in place (out index_map constant in k, initialized at slot 0).
+
+TPU tiling: every block's last two dims are either (8, 128)-aligned or equal
+to the array's.  So x is viewed as (n_cb, 1, bn) and fetched as a (1, bn)
+row, and y as (n_rb, bm, 1): each band's partial sums are a lane reduction
+of the (bm, bn) tile times that row.
 """
 from __future__ import annotations
 
@@ -20,9 +25,16 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from ..core.sparse import BellMeta
+from .solve_step import I0, check_kernel_dtype, default_interpret
 
 
-def _kernel(cols_ref, vals_ref, x_ref, y_ref):
+# int32 slots of the column-block table per launch: the prefetched table
+# lives in SMEM (1 MiB on v5e), so larger tables are cut into row-band chunks,
+# each one launch over the same value and x operands.
+SMEM_TABLE_SLOTS = 1 << 17
+
+
+def _kernel(off_ref, cols_ref, vals_ref, x_ref, y_ref):
     s = pl.program_id(1)
 
     @pl.when(s == 0)
@@ -30,8 +42,8 @@ def _kernel(cols_ref, vals_ref, x_ref, y_ref):
         y_ref[...] = jnp.zeros_like(y_ref)
 
     blk = vals_ref[0, 0]            # (bm, bn)
-    xv = x_ref[0]                   # (bn,)
-    y_ref[0, :] += jnp.dot(blk, xv, preferred_element_type=y_ref.dtype)
+    xv = x_ref[0]                   # (1, bn)
+    y_ref[0] += jnp.sum(blk * xv, axis=1, keepdims=True)     # (bm, 1)
 
 
 @functools.partial(jax.jit, static_argnums=(0, 4))
@@ -48,25 +60,33 @@ def bell_spmv_pallas(meta: BellMeta, block_cols: jax.Array,
     (Static argnum, so None resolves once at trace time.)
     """
     if interpret is None:
-        from .solve_step import default_interpret
         interpret = default_interpret()
+    check_kernel_dtype("bell_spmv", x.dtype, interpret)
     bm, bn, k, n_rb = meta.bm, meta.bn, meta.k, meta.n_rb
     xp = jnp.pad(x, (0, meta.m_pad - x.shape[0]))
-    x2 = xp.reshape(meta.n_cb, bn)
-
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,
-        grid=(n_rb, k),
-        in_specs=[
-            pl.BlockSpec((1, 1, bm, bn), lambda r, s, cols: (r, s, 0, 0)),
-            pl.BlockSpec((1, bn), lambda r, s, cols: (cols[r, s], 0)),
-        ],
-        out_specs=pl.BlockSpec((1, bm), lambda r, s, cols: (r, 0)),
-    )
-    out = pl.pallas_call(
-        _kernel,
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((n_rb, bm), x.dtype),
-        interpret=interpret,
-    )(block_cols.astype(jnp.int32), bell_vals, x2)
-    return out.reshape(meta.n_pad)
+    x3 = xp.reshape(meta.n_cb, 1, bn)
+    cols = block_cols.astype(jnp.int32)
+    rows_per = max(1, SMEM_TABLE_SLOTS // k)
+    outs = []
+    for r0 in range(0, n_rb, rows_per):
+        nr = min(rows_per, n_rb - r0)
+        grid_spec = pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2,
+            grid=(nr, k),
+            in_specs=[
+                pl.BlockSpec((1, 1, bm, bn),
+                             lambda r, s, off, c: (off[0] + r, s, I0, I0)),
+                pl.BlockSpec((1, 1, bn),
+                             lambda r, s, off, c: (c[r * k + s], I0, I0)),
+            ],
+            out_specs=pl.BlockSpec((1, bm, 1),
+                                   lambda r, s, off, c: (r, I0, I0)),
+        )
+        outs.append(pl.pallas_call(
+            _kernel,
+            grid_spec=grid_spec,
+            out_shape=jax.ShapeDtypeStruct((nr, bm, 1), x.dtype),
+            interpret=interpret,
+        )(jnp.full((1,), r0, jnp.int32), cols[r0:r0 + nr].reshape(-1),
+          bell_vals, x3))
+    return jnp.concatenate(outs).reshape(meta.n_pad)

@@ -27,6 +27,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from .solve_step import I0, check_kernel_dtype, default_interpret
+
 
 @dataclasses.dataclass(frozen=True)
 class Stencil5Meta:
@@ -66,8 +68,13 @@ def _kernel(val_ref, xu_ref, xc_ref, xd_ref, y_ref):
 
 @functools.partial(jax.jit, static_argnums=(0, 3))
 def stencil5_pallas(meta: Stencil5Meta, val5: jax.Array, x: jax.Array,
-                    interpret: bool = True) -> jax.Array:
-    """Apply the stencil.  ``val5``: (5, nx, ny) planes; ``x``: (nx, ny)."""
+                    interpret: bool = None) -> jax.Array:
+    """Apply the stencil.  ``val5``: (5, nx, ny) planes; ``x``: (nx, ny).
+
+    ``interpret=None`` compiles on TPU/GPU and emulates elsewhere."""
+    if interpret is None:
+        interpret = default_interpret()
+    check_kernel_dtype("stencil5", x.dtype, interpret)
     nxp, nyp, bm = meta.nx_pad, meta.ny_pad, meta.bm
     nb = meta.n_bands
     vp = jnp.pad(val5, ((0, 0), (0, nxp - meta.nx), (0, nyp - meta.ny)))
@@ -78,12 +85,12 @@ def stencil5_pallas(meta: Stencil5Meta, val5: jax.Array, x: jax.Array,
         _kernel,
         grid=grid,
         in_specs=[
-            pl.BlockSpec((5, bm, nyp), lambda i: (0, i, 0)),
-            pl.BlockSpec((bm, nyp), lambda i: (jnp.maximum(i - 1, 0), 0)),
-            pl.BlockSpec((bm, nyp), lambda i: (i, 0)),
-            pl.BlockSpec((bm, nyp), lambda i: (jnp.minimum(i + 1, nb - 1), 0)),
+            pl.BlockSpec((5, bm, nyp), lambda i: (I0, i, I0)),
+            pl.BlockSpec((bm, nyp), lambda i: (jnp.maximum(i - 1, 0), I0)),
+            pl.BlockSpec((bm, nyp), lambda i: (i, I0)),
+            pl.BlockSpec((bm, nyp), lambda i: (jnp.minimum(i + 1, nb - 1), I0)),
         ],
-        out_specs=pl.BlockSpec((bm, nyp), lambda i: (i, 0)),
+        out_specs=pl.BlockSpec((bm, nyp), lambda i: (i, I0)),
         out_shape=jax.ShapeDtypeStruct((nxp, nyp), x.dtype),
         interpret=interpret,
     )(vp, xp, xp, xp)

@@ -17,7 +17,8 @@ vector.  The kernels here run one supernode per grid lane:
 Each kernel's math lives in a single-lane ``sn_*_body`` function in
 ``kernels/ref.py`` — the pure-jnp oracles vmap those bodies, and the Pallas
 kernels call the very same bodies on their per-lane VMEM blocks, so
-kernel-vs-ref parity is structural.  Per-lane true sizes (w, r) ride in SMEM;
+kernel-vs-ref parity is structural.  Per-lane true sizes (w, r) are
+scalar-prefetched into SMEM;
 pad rows/columns are masked inside the body (gathered pads hold scratch
 garbage).  Every kernel declares its traffic model via a
 ``passes = (reads, writes)`` attribute in units of full operand arrays.
@@ -34,13 +35,17 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from . import ref as _ref
-from .solve_step import default_interpret
+from .solve_step import I0, check_kernel_dtype, default_interpret
 
 __all__ = ["panel_factor", "schur_update", "block_trsv", "default_interpret"]
 
 
-def _scalar_spec():
-    return pl.BlockSpec((1, 1), lambda i: (i, 0), memory_space=pltpu.SMEM)
+def _lane_spec(*block):
+    """Block ``(1,) + block`` of lane i; index maps return int32.  Each
+    block has the full array's last two dims, so the (8, 128) tiling rule
+    holds at any bucket shape."""
+    zeros = (I0,) * len(block)
+    return pl.BlockSpec((1,) + block, lambda i, *_: (i,) + zeros)
 
 
 def panel_factor(P, Q, wvec, rvec, tau, bkm, *, pairs=False, guard=True,
@@ -58,44 +63,35 @@ def panel_factor(P, Q, wvec, rvec, tau, bkm, *, pairs=False, guard=True,
     k, m, wb = P.shape
     rb = Q.shape[2]
     dtype = P.dtype
+    check_kernel_dtype("panel_factor", dtype, interpret)
 
     def kern(wv, rv, tv, bk, p, q, po, qo, nb):
-        w = wv[0, 0]
-        r = rv[0, 0]
-        t = tv[0, 0]
-        mask = bk[0] != 0
+        i = pl.program_id(0)
         Pn, Qn, bad = _ref.sn_panel_factor_body(
-            p[0], q[0], w, r, t, mask, pairs=pairs, guard=guard)
+            p[0], q[0], wv[i], rv[i], tv[...], bk[0], pairs=pairs,
+            guard=guard)
         po[0] = Pn
         qo[0] = Qn
-        nb[0, 0] = bad
+        nb[0] = bad
 
     Po, Qo, nbad = pl.pallas_call(
         kern,
-        grid=(k,),
-        in_specs=[
-            _scalar_spec(), _scalar_spec(),
-            pl.BlockSpec((1, 1), lambda i: (0, 0), memory_space=pltpu.SMEM),
-            pl.BlockSpec((1, wb), lambda i: (i, 0)),
-            pl.BlockSpec((1, m, wb), lambda i: (i, 0, 0)),
-            pl.BlockSpec((1, wb, rb), lambda i: (i, 0, 0)),
-        ],
-        out_specs=[
-            pl.BlockSpec((1, m, wb), lambda i: (i, 0, 0)),
-            pl.BlockSpec((1, wb, rb), lambda i: (i, 0, 0)),
-            pl.BlockSpec((1, 1), lambda i: (i, 0), memory_space=pltpu.SMEM),
-        ],
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2, grid=(k,),          # wvec, rvec → SMEM
+            in_specs=[pl.BlockSpec((1, 1), lambda i, *_: (I0, I0)),
+                      _lane_spec(1, wb), _lane_spec(m, wb),
+                      _lane_spec(wb, rb)],
+            out_specs=[_lane_spec(m, wb), _lane_spec(wb, rb),
+                       _lane_spec(1, 1)]),
         out_shape=[
             jax.ShapeDtypeStruct((k, m, wb), dtype),
             jax.ShapeDtypeStruct((k, wb, rb), dtype),
-            jax.ShapeDtypeStruct((k, 1), dtype),
+            jax.ShapeDtypeStruct((k, 1, 1), dtype),
         ],
         interpret=interpret,
-    )(wvec.reshape(k, 1).astype(jnp.int32),
-      rvec.reshape(k, 1).astype(jnp.int32),
+    )(wvec.astype(jnp.int32), rvec.astype(jnp.int32),
       jnp.asarray(tau, dtype).reshape(1, 1),
-      bkm.astype(dtype),
-      P, Q)
+      bkm.astype(dtype).reshape(k, 1, wb), P, Q)
     return Po, Qo, jnp.sum(nbad)
 
 
@@ -114,20 +110,19 @@ def schur_update(P, Q, *, interpret=None):
     k, m, wb = P.shape
     rb = Q.shape[2]
     dtype = P.dtype
+    check_kernel_dtype("schur_update", dtype, interpret)
 
     def kern(p, q, s):
         s[0] = jax.lax.dot_general(
             p[0][wb:, :], q[0], (((1,), (0,)), ((), ())),
+            precision=jax.lax.Precision.HIGHEST,
             preferred_element_type=dtype)
 
     return pl.pallas_call(
         kern,
         grid=(k,),
-        in_specs=[
-            pl.BlockSpec((1, m, wb), lambda i: (i, 0, 0)),
-            pl.BlockSpec((1, wb, rb), lambda i: (i, 0, 0)),
-        ],
-        out_specs=pl.BlockSpec((1, rb, rb), lambda i: (i, 0, 0)),
+        in_specs=[_lane_spec(m, wb), _lane_spec(wb, rb)],
+        out_specs=_lane_spec(rb, rb),
         out_shape=jax.ShapeDtypeStruct((k, rb, rb), dtype),
         interpret=interpret,
     )(P, Q)
@@ -148,26 +143,26 @@ def block_trsv(D, y, wvec, bkm, *, mode, pairs=False, interpret=None):
         interpret = default_interpret()
     k, wb = y.shape
     dtype = D.dtype
+    check_kernel_dtype("block_trsv", dtype, interpret)
+    # the body's orientation: a column where D's columns are subtracted
+    vec = (wb, 1) if mode in ("l", "u") else (1, wb)
 
     def kern(wv, bk, d, yy, xo):
-        w = wv[0, 0]
-        mask = bk[0] != 0
-        xo[0] = _ref.sn_trsv_body(d[0], yy[0], w, mask, mode=mode,
-                                  pairs=pairs)
+        xo[0] = _ref.sn_trsv_body(d[0], yy[0], wv[pl.program_id(0)], bk[0],
+                                  mode=mode, pairs=pairs)
 
-    return pl.pallas_call(
+    x = pl.pallas_call(
         kern,
-        grid=(k,),
-        in_specs=[
-            _scalar_spec(),
-            pl.BlockSpec((1, wb), lambda i: (i, 0)),
-            pl.BlockSpec((1, wb, wb), lambda i: (i, 0, 0)),
-            pl.BlockSpec((1, wb), lambda i: (i, 0)),
-        ],
-        out_specs=pl.BlockSpec((1, wb), lambda i: (i, 0)),
-        out_shape=jax.ShapeDtypeStruct((k, wb), dtype),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1, grid=(k,),          # wvec → SMEM
+            in_specs=[_lane_spec(1, wb), _lane_spec(wb, wb),
+                      _lane_spec(*vec)],
+            out_specs=_lane_spec(*vec)),
+        out_shape=jax.ShapeDtypeStruct((k,) + vec, dtype),
         interpret=interpret,
-    )(wvec.reshape(k, 1).astype(jnp.int32), bkm.astype(dtype), D, y)
+    )(wvec.astype(jnp.int32), bkm.astype(dtype).reshape(k, 1, wb), D,
+      y.reshape((k,) + vec))
+    return x.reshape(k, wb)
 
 
 block_trsv.passes = (2, 1)

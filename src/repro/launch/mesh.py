@@ -11,10 +11,8 @@ def make_production_mesh(*, multi_pod: bool = False):
     """16×16 = 256 chips/pod; 2 pods = 512 chips multi-pod."""
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    if hasattr(jax.sharding, "AxisType"):      # absent on older jax releases
-        return jax.make_mesh(shape, axes,
-                             axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
-    return jax.make_mesh(shape, axes)
+    return jax.make_mesh(shape, axes,
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
 
 
 def make_host_mesh(n: int = 1, axes=("data",)):

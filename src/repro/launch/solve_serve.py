@@ -258,6 +258,8 @@ def main(argv=None):
     ap.add_argument("--max-batch", type=int, default=32)
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args(argv)
+    from .compile_cache import enable_compile_cache
+    enable_compile_cache()
 
     kw = dict(n_requests=args.requests, grid=args.grid,
               n_patterns=args.patterns, max_batch=args.max_batch,

@@ -52,7 +52,7 @@ def compressed_halo_exchange(x: jax.Array, h_lo: int, h_hi: int, axis: str):
     iteration.  Each halo zone is dequantized with the *sender's* scale
     (exchanged alongside).  Accuracy impact is benchmarked, not assumed
     (EXPERIMENTS.md §Perf)."""
-    p = lax.psum(1, axis)     # static fold; lax.axis_size absent on old jax
+    p = lax.axis_size(axis)
     idx = lax.axis_index(axis)
     q, scale = quantize_int8(x)
     qi = q.astype(jnp.int32)

@@ -27,7 +27,8 @@ def test_mini_multipod_dryrun():
         from repro.models import transformer as T
         from repro.optim.adamw import AdamWConfig, init_opt_state
 
-        mesh = jax.make_mesh((2, 2, 2), ("pod", "data", "model"))
+        mesh = jax.make_mesh((2, 2, 2), ("pod", "data", "model"),
+                             axis_types=(jax.sharding.AxisType.Auto,) * 3)
         rules = sh.baseline_rules(mesh)
         cfg = smoke_variant(get_config("llama3.2-1b"))
         shape = ShapeConfig("t", seq_len=64, global_batch=8, kind="train")

@@ -279,3 +279,33 @@ def test_default_interpret_matches_platform():
     A = poisson2d(8)
     plan = A.plan(backend="pallas", method="cg")
     assert plan.artifacts["kernel"].interpret == expect
+
+
+def test_compiled_kernels_refuse_f64():
+    """Compiled (``interpret=False``), every solve-path kernel names a
+    float64 operand instead of lowering it; Mosaic has no f64 type."""
+    from repro.kernels import supernode as ksn
+    from repro.kernels.spmv_bell import bell_spmv_pallas
+    from repro.kernels.stencil5 import stencil5_pallas
+    from repro.core.sparse import BellMeta
+    f64 = jnp.float64
+    x = jnp.zeros(1024, f64)
+    meta = BellMeta(bm=8, bn=128, n_rb=128, n_cb=8, k=1, n_pad=1024,
+                    m_pad=1024, fill=1.0)
+    P, Q = jnp.zeros((4, 6, 2), f64), jnp.zeros((4, 2, 4), f64)
+    w, m = jnp.ones(4, jnp.int32), jnp.zeros((4, 2), bool)
+    calls = [
+        lambda: _fk.fused_cg_halfstep(x, x, x, x, f64(1), interpret=False),
+        lambda: stencil5_pallas(Stencil5Meta(nx=8, ny=8),
+                                jnp.zeros((5, 8, 8), f64),
+                                jnp.zeros((8, 8), f64), False),
+        lambda: bell_spmv_pallas(meta, jnp.zeros((128, 1), jnp.int32),
+                                 jnp.zeros((128, 1, 8, 128), f64), x, False),
+        lambda: ksn.panel_factor(P, Q, w, w, 1.0, m, interpret=False),
+        lambda: ksn.schur_update(P, Q, interpret=False),
+        lambda: ksn.block_trsv(P[:, :2], P[:, 0], w, m, mode="l",
+                               interpret=False),
+    ]
+    for call in calls:
+        with pytest.raises(ValueError, match="float64"):
+            call()

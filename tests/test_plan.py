@@ -523,3 +523,21 @@ def test_gmres_matvec_count_per_cycle():
     # body (1 scan-traced Arnoldi step + 1 residual update) = 3.  The old
     # loop re-derived the residual in cond and at exit → 5 traced matvecs.
     assert calls["n"] <= 3, calls["n"]
+
+
+@pytest.mark.parametrize("backend,ng,kernels", [
+    ("pallas", 16, "bell, fused step"),
+    ("direct", 40, "panel_factor, schur_update, block_trsv"),
+])
+def test_compiled_platform_refuses_f64_values(monkeypatch, backend, ng,
+                                              kernels):
+    """Where the Pallas kernels compile (TPU), f64 values are refused at
+    setup with the dtype and the kernels named — never emulated or rerouted.
+    The platform probe is steered here; nothing is compiled."""
+    from repro.kernels import solve_step
+    monkeypatch.setattr(solve_step, "default_interpret", lambda: False)
+    A = poisson2d(ng)                         # float64 values
+    plan = get_plan(A, make_config(A, backend=backend))
+    plan.check_dtype(jnp.float32)             # the chip path passes
+    with pytest.raises(ValueError, match=f"{kernels}.*float64"):
+        plan.setup(A)

@@ -94,6 +94,39 @@ def test_partition_factor_parity_vs_scalar(case):
                                    rtol=1e-9, atol=1e-9)
 
 
+@pytest.mark.parametrize("case", ["poisson2d", "graph"])
+def test_capped_launches_and_pieces_match_scalar(case, monkeypatch):
+    """A bucket wider than the lane cap (``SN_MAX_LANES`` where the Pallas
+    panel kernels run) runs as several launches of one program, and the
+    factor storage is scattered in ``_SCATTER_PIECE`` pieces; the factors
+    match the scalar path's and both sweeps solve."""
+    from repro.core import direct as _direct
+    A = poisson2d(16) if case == "poisson2d" else graph_laplacian(300, seed=3)
+    row, col, n = np.asarray(A.row), np.asarray(A.col), A.shape[0]
+    monkeypatch.setattr(_direct, "_lane_cap", lambda: 4)
+    art_cap = symbolic_factor(row, col, n, supernodal="on")
+    launches = [bk for lvl in art_cap.snode.schedule for bk in lvl]
+    assert max(bk.pidx.shape[0] for bk in launches) == 4
+    art_off = symbolic_factor(row, col, n, supernodal="off")
+
+    v = jnp.asarray(A.val)
+    C_off = numeric_factor(art_off, v)
+    monkeypatch.setattr(_direct, "_SCATTER_PIECE", 512)
+    assert art_cap.nnzF > 2 * 512
+    C_cap = numeric_factor(art_cap, v)
+    np.testing.assert_allclose(np.asarray(C_cap), np.asarray(C_off),
+                               rtol=1e-10, atol=1e-10)
+    Ad = np.zeros((n, n))
+    np.add.at(Ad, (row, col), np.asarray(A.val))
+    b = np.random.default_rng(0).standard_normal(n)
+    for transposed in (False, True):
+        x = factored_solve(art_cap, C_cap, jnp.asarray(b),
+                           transposed=transposed)
+        np.testing.assert_allclose(
+            np.asarray(x), np.linalg.solve(Ad.T if transposed else Ad, b),
+            rtol=1e-9, atol=1e-9)
+
+
 def test_panel_solve_matches_dense_1e8():
     A = poisson2d(20)          # 400 dof
     b = jnp.asarray(np.random.default_rng(1).standard_normal(A.shape[0]))
@@ -102,6 +135,27 @@ def test_panel_solve_matches_dense_1e8():
     xd = A.solve(b, backend="dense", method="cholesky")
     np.testing.assert_allclose(np.asarray(x), np.asarray(xd),
                                rtol=1e-10, atol=1e-8)
+
+
+@pytest.mark.parametrize("dtype", [np.float32, np.float64])
+def test_direct_converged_is_the_backward_error(dtype):
+    """``converged`` of a direct solve is ``backward_error <= tol`` (or
+    ``||r||_inf <= atol``): the factors of the matrix pass, in f32 as in
+    f64, and factors of a clearly different matrix fail."""
+    from repro import sla
+    from repro.core.sparse import backward_error
+    A = poisson2d(24, dtype=dtype)
+    b = jnp.asarray(np.random.default_rng(2).uniform(0.5, 1.5, A.shape[0])
+                    .astype(dtype))
+    berr = lambda x: float(backward_error(A.val, A.row, A.col, A.shape[0],
+                                          x, b))
+    with _options.options(supernodal="on"):
+        res = sla.solve_with_info(A, b, backend="direct", tol=1e-5)
+        plan = sla.get_plan(A, backend="direct", tol=1e-5)
+        wrong = plan.setup(A.with_values(A.val * 1.5))
+    assert bool(res.converged) and berr(res.x) <= 1e-5
+    xw, info = plan.backend.solve(plan, wrong, A, b, None, plan.cfg)
+    assert not bool(info.converged) and berr(xw) > 1e-4
 
 
 def test_auto_gate_declines_sequential_chain():
