@@ -30,7 +30,9 @@ A phase fails the run (non-zero exit, no JSON line) when it raises, when a
 solve does not converge, when the backward error
 ||b - Ax||_inf / (||A||_inf ||x||_inf + ||b||_inf) exceeds BERR_BOUND, or when
 the plan did not choose the compiled Pallas path the phase exists to run.
-Wall seconds are smoke wall time (compilation included), not a benchmark.
+Each phase line ends with its last solve's record (``sla.solve_records``):
+``host_ms``, the host time outside the wait for the device; ``wait_ms``, the
+``solve.wait`` span; ``lowerings``, the programs JAX lowered in that solve.
 """
 from __future__ import annotations
 
@@ -39,7 +41,6 @@ import json
 import math
 import os
 import sys
-import time
 
 ROOT = os.path.dirname(os.path.abspath(__file__))
 SRC = os.path.join(ROOT, "src")
@@ -147,6 +148,19 @@ class Smoke:
         require(math.isfinite(berr) and berr <= BERR_BOUND,
                 f"{name}: backward error {berr:.3e} > {BERR_BOUND:.0e}")
 
+    def last_solve(self) -> dict:
+        """Fields of the newest eager solve record: host ms outside the
+        wait for the device, the wait's ms and the programs lowered."""
+        recs = [r for r in self.sla.solve_records() if not r["traced"] and
+                r["name"] in ("sla.solve", "sla.serve_batch", "plan.solve")]
+        if not recs:
+            return {}
+        r = recs[-1]
+        wait = r["incl_s"].get("solve.wait", 0.0)
+        return dict(host_ms=f"{(r['seconds'] - wait) * 1e3:.1f}",
+                    wait_ms=f"{wait * 1e3:.1f}",
+                    lowerings=r["counters"].get("jax_lowerings", 0))
+
     def kernel_plan(self, A, **kw):
         plan = self.sla.get_plan(A, **kw)
         return plan, plan.artifacts.get("kernel")
@@ -160,7 +174,6 @@ class Smoke:
         jax, jnp, sla = self.jax, self.jnp, self.sla
         from repro.data.poisson import poisson2d_vc
         ng = 128 if self.rehearse else 4096
-        t0 = time.perf_counter()
         key = jax.random.PRNGKey(self.seed)
         kappa = jnp.exp(0.3 * jax.random.normal(key, (ng, ng), jnp.float32))
         A = poisson2d_vc(kappa, use_stencil_kernel=True)
@@ -172,11 +185,10 @@ class Smoke:
         plan, kp = self.kernel_plan(A, **kw)
         fused = self.dispatch._fuse_enabled(kp)
         be = self.berr(A.val, A.row, A.col, n, res.x, b)
-        dt = time.perf_counter() - t0
         report("stencil", dof=n, nnz=A.nnz, backend=plan.cfg.backend,
                kernel=kp.choice, interpret=kp.interpret, fused_step=fused,
                iters=int(res.iterations), converged=bool(res.converged),
-               berr=f"{be:.3e}", smoke_wall_s=f"{dt:.1f}")
+               berr=f"{be:.3e}", **self.last_solve())
         self.check_solve("stencil", res, be)
         self.need_pallas("stencil", kp.choice == "stencil"
                          and kp.interpret is False, "the compiled stencil kernel")
@@ -188,7 +200,6 @@ class Smoke:
         from repro.data.poisson import poisson2d_vc, vc_coefficients
         A, kw, b = self.stencil_A, self.stencil_kw, self.stencil_b
         ng = int(round(math.sqrt(A.shape[0])))
-        t0 = time.perf_counter()
         key = jax.random.PRNGKey(self.seed)
         kappa = jnp.exp(0.3 * jax.random.normal(key, (ng, ng), jnp.float32))
 
@@ -218,11 +229,10 @@ class Smoke:
         gs = jax.grad(loss_sparse)(ks)
         gd = jax.grad(loss_dense)(ks)
         rel = float(jnp.linalg.norm(gs - gd) / jnp.linalg.norm(gd))
-        dt = time.perf_counter() - t0
         report("gradient", dof=ng * ng, grad_finite=finite,
                grad_norm=f"{float(jnp.linalg.norm(g)):.4e}",
                analyze=analyze, dense_check_ng=ns,
-               dense_rel_err=f"{rel:.3e}", smoke_wall_s=f"{dt:.1f}")
+               dense_rel_err=f"{rel:.3e}", **self.last_solve())
         require(finite, "gradient: non-finite entries")
         require(analyze == 1, f"gradient: analyze ran {analyze} times, not 1")
         require(rel <= 1e-3, f"gradient: rel err vs dense {rel:.3e} > 1e-3")
@@ -232,7 +242,6 @@ class Smoke:
         from repro.core import direct as _direct
         from repro.data.poisson import poisson2d
         ng = 40 if self.rehearse else 316
-        t0 = time.perf_counter()
         A = poisson2d(ng, dtype=np.float32)
         n = ng * ng
         b = jnp.asarray(np.random.default_rng(self.seed).uniform(
@@ -249,13 +258,12 @@ class Smoke:
         exact = float(np.sum(np.log(4.0 - c[:, None] - c[None, :])))
         ld_rel = abs(float(logdet) - exact) / abs(exact)
         factorize = sla.PLAN_STATS["factorize"]
-        dt = time.perf_counter() - t0
         report("direct", dof=n, nnz=A.nnz, backend=plan.cfg.backend,
                method=plan.cfg.method, supernodal=snode, panel_kernels=pallas,
                iters=int(res.iterations), converged=bool(res.converged),
                berr=f"{be:.3e}", slogdet_sign=float(sign),
                slogdet_rel_err=f"{ld_rel:.3e}", factorize=factorize,
-               smoke_wall_s=f"{dt:.1f}")
+               **self.last_solve())
         self.check_solve("direct", res, be)
         require(float(sign) == 1.0 and ld_rel <= 1e-4,
                 f"direct: slogdet ({float(sign)}, rel err {ld_rel:.3e})")
@@ -267,9 +275,7 @@ class Smoke:
         jnp, np, sla = self.jnp, self.np, self.sla
         from repro.data.graphs import graph_laplacian
         n = 4096 if self.rehearse else 1 << 18
-        t0 = time.perf_counter()
         A = graph_laplacian(n, seed=self.seed, dtype=np.float32)
-        t_host = time.perf_counter() - t0
         b = jnp.asarray(np.random.default_rng(self.seed).normal(
             size=n).astype(np.float32))
         kw = dict(backend="jnp", method="cg", precond="amg", tol=1e-6,
@@ -278,13 +284,12 @@ class Smoke:
         plan, kp = self.kernel_plan(A, **kw)
         fused = self.dispatch._fuse_enabled(kp)
         be = self.berr(A.val, A.row, A.col, n, res.x, b)
-        dt = time.perf_counter() - t0
         report("graph", dof=n, nnz=A.nnz, backend=plan.cfg.backend,
                kernel=kp.choice, kernel_reason=repr(kp.reason),
                interpret=kp.interpret, fused_step=fused,
                iters=int(res.iterations), converged=bool(res.converged),
-               berr=f"{be:.3e}", host_build_s=f"{t_host:.1f}",
-               smoke_wall_s=f"{dt:.1f}")
+               berr=f"{be:.3e}",
+               **self.last_solve())
         self.check_solve("graph", res, be)
         self.need_pallas("graph", fused, "the fused CG steps")
 
@@ -295,7 +300,6 @@ class Smoke:
         # block-ELL is adopted at fill >= 1/64: 2-D Poisson up to 64^2
         grids = (16, 24) if self.rehearse else (48, 64)
         n_req, max_batch = 48, 16
-        t0 = time.perf_counter()
         rng = np.random.default_rng(self.seed)
         bases = [poisson2d(g, dtype=np.float32) for g in grids]
         opts = dict(backend="jnp", method="cg", precond="jacobi", tol=1e-6)
@@ -326,14 +330,13 @@ class Smoke:
         kps = [self.kernel_plan(A, **opts)[1] for A in plans.values()]
         kinds = sorted({kp.choice for kp in kps})
         fused = all(self.dispatch._fuse_enabled(kp) for kp in kps)
-        dt = time.perf_counter() - t0
         report("serve", requests=n_req, patterns=len(grids),
                dof="/".join(str(g * g) for g in grids),
                dispatches=server.stats["dispatches"],
                analyze=sla.PLAN_STATS["analyze"], kernel=",".join(kinds),
                interpret=any(kp.interpret for kp in kps), fused_step=fused,
                parity_rel=f"{worst_par:.3e}", berr=f"{worst_be:.3e}",
-               smoke_wall_s=f"{dt:.1f}")
+               **self.last_solve())
         require(worst_par <= 1e-4, f"serve: batched vs sequential {worst_par:.3e}")
         require(worst_be <= BERR_BOUND, f"serve: backward error {worst_be:.3e}")
         self.need_pallas("serve", kinds == ["bell"] and not any(
@@ -345,7 +348,6 @@ class Smoke:
         from repro.core.distributed import DSparseTensor
         from repro.data.poisson import poisson2d
         ng = 64 if self.rehearse else 2048
-        t0 = time.perf_counter()
         A = poisson2d(ng, dtype=np.float32)
         n = ng * ng
         val, row, col = np.asarray(A.val), np.asarray(A.row), np.asarray(A.col)
@@ -374,7 +376,7 @@ class Smoke:
             x, info = D.solve_with_info(bs, **kw)
             xg = D.gather_global(x)
             progress(f"mesh: {p}-device solve done, {int(info.iters)} "
-                     f"iterations, {time.perf_counter() - t0:.1f} s")
+                     f"iterations")
             require(bool(info.converged), f"mesh: {p}-device solve did not "
                     f"converge in {int(info.iters)} iterations")
             be = self.berr(A.val, A.row, A.col, n, jnp.asarray(xg), b_d)
@@ -383,19 +385,17 @@ class Smoke:
                 ws = D.stack_vector(w)
                 g4 = np.asarray(jax.grad(lambda lv: jnp.vdot(
                     ws, D.with_values(lv).solve(bs, **kw)))(D.lval))
-                progress(f"mesh: gradient done, "
-                         f"{time.perf_counter() - t0:.1f} s")
+                progress("mesh: gradient done")
         x4, be4, it4, d4, width4 = out[4]
         x1, be1, it1, _, width1 = out[1]
         rel = float(np.max(np.abs(x4 - x1)) / np.max(np.abs(x1)))
         err = float(np.max(np.abs(x4 - x_true)))
         gfin = bool(np.all(np.isfinite(g4)))
-        dt = time.perf_counter() - t0
         report("mesh", dof=n, nnz=int(val.size), devices=d4,
                ell_width=f"{width4}/{width1}", iters_4=it4, iters_1=it1,
                berr_4=f"{be4:.3e}", berr_1=f"{be1:.3e}", x_rel_4v1=f"{rel:.3e}",
                x_err_vs_true=f"{err:.3e}", grad_finite=gfin,
-               smoke_wall_s=f"{dt:.1f}")
+               **self.last_solve())
         require(be4 <= BERR_BOUND and be1 <= BERR_BOUND,
                 f"mesh: backward errors {be4:.3e} / {be1:.3e}")
         require(rel <= 1e-4, f"mesh: 4-device vs 1-device {rel:.3e} > 1e-4")

@@ -74,6 +74,8 @@ from . import direct as _direct
 from . import options as _options
 from . import precond as _precond
 from . import solvers as _solvers
+from . import spans as _spans
+from .spans import count, span, spanned
 from .sparse import (SparseTensor, backward_error, build_bell, coo_matvec,
                      has_full_diagonal)
 
@@ -85,30 +87,17 @@ from .sparse import (SparseTensor, backward_error, build_bell, coo_matvec,
 # read/write aliases — see the module __getattr__ / class swap at the bottom.
 DEFAULT_MAXITER = 2000
 
-# observable analyze/setup/cache counters (reset with ``reset_plan_stats``)
-PLAN_STATS: Dict[str, int] = {
-    "analyze": 0,          # SolverPlan constructions (pattern analyses)
-    "setup": 0,            # values-dependent setups actually executed
-    "setup_reuse": 0,      # setups served from the per-values memo
-    "factorize": 0,        # numeric factorizations run by the direct backend
-    "cache_hit": 0,        # plan served from a SparseTensor's plan cache
-    "cache_miss": 0,       # plan analyzed fresh
-    "transpose_shared": 0,  # adjoint reused the forward plan (or its factors)
-    "t_partition": 0,      # distributed Aᵀ partitions built (once per plan)
-    "coarsen": 0,          # AMG pattern coarsenings (symbolic, once/pattern)
-    "galerkin": 0,         # AMG numeric Galerkin products (once/values array)
-    "kernel_plan": 0,      # BELL conversions run by the analyze-time kernel plan
-    "evictions": 0,        # plans dropped by the bounded LRU plan cache
-    "jac_color": 0,        # Jacobian pattern colorings (once per SparseNewton)
-    "jac_assemble": 0,     # numeric Jacobian assemblies (jvp probe sweeps)
-}
+# observable analyze/setup/cache/compile counters, defined beside the span
+# recorder (``core/spans.py``) whose ``count`` is their only writer
+PLAN_STATS = _spans.PLAN_STATS
 
 
 def reset_plan_stats() -> None:
-    """Zero every ``PLAN_STATS`` counter (tests and benchmarks call this
-    before a measured region)."""
+    """Zero every ``PLAN_STATS`` counter and drop the completed solve
+    records (tests and benchmarks call this before a measured region)."""
     for k in PLAN_STATS:
         PLAN_STATS[k] = 0
+    _spans.clear_records()
 
 
 class PlanCache(collections.OrderedDict):
@@ -158,7 +147,7 @@ class PlanCache(collections.OrderedDict):
     def _evict_oldest(self) -> None:
         old, _ = self.popitem(last=False)
         self.total_bytes -= self._sizes.pop(old, 0)
-        PLAN_STATS["evictions"] += 1
+        count("evictions")
 
     def __setitem__(self, key, value):
         if key in self:            # replace = delete + fresh LRU insert
@@ -205,6 +194,7 @@ class KernelPlan:
     t_bell: Optional[tuple] = None
 
 
+@spanned("analyze.kernel_plan")
 def _build_kernel_plan(pattern, prefer: str) -> KernelPlan:
     """Freeze the matvec kernel for one analyzed pattern.
 
@@ -231,7 +221,7 @@ def _build_kernel_plan(pattern, prefer: str) -> KernelPlan:
             return KernelPlan("coo", "traced pattern (no eager conversion)",
                               interp)
         bell = build_bell(pattern.row, pattern.col, pattern.shape)
-        PLAN_STATS["kernel_plan"] += 1
+        count("kernel_plan")
     meta = bell[0]
     # minimum BELL fill (nnz over padded slot capacity) for the kernel plan
     # to adopt the block-ELL layout on its own; below it the padding work
@@ -247,7 +237,7 @@ def _build_kernel_plan(pattern, prefer: str) -> KernelPlan:
         t_bell = bell                       # Aᵀ shares A's layout outright
     elif concrete:
         t_bell = build_bell(pattern.col, pattern.row, (m, n))
-        PLAN_STATS["kernel_plan"] += 1
+        count("kernel_plan")
     else:
         t_bell = None          # traced indices: adjoint takes the generic path
     return KernelPlan("bell", f"fill={meta.fill:.4f}", interp, bell, t_bell)
@@ -476,7 +466,7 @@ class DirectBackend(Backend):
         return ("panel_factor", "schur_update", "block_trsv")
 
     def setup(self, plan, A):
-        PLAN_STATS["factorize"] += 1
+        count("factorize")
         return _direct.numeric_factor(plan.artifacts["direct"], A.val)
 
     def solve(self, plan, C, A, b, x0, cfg):
@@ -562,7 +552,8 @@ class IterativeBackend(Backend):
         solve-time read of ``options.fused_step``."""
         mv = self._matvec_from_val(plan, A.val)
         pre = plan.artifacts["precond"]
-        pstate = pre.refresh_state(A, mv)
+        with span("precond.refresh"):
+            pstate = pre.refresh_state(A, mv)
         dinv = pre.fused_diag(A)
         return A.val, pstate, dinv
 
@@ -575,40 +566,35 @@ class IterativeBackend(Backend):
         kp = plan.artifacts.get("kernel")
         fuse = _fuse_enabled(kp)
         interp = kp.interpret if kp is not None else None
-        M = plan.artifacts["precond"].make_apply(pstate, mv, fused=fuse,
-                                                 interpret=interp)
+        with span("precond.make_apply"):
+            M = plan.artifacts["precond"].make_apply(pstate, mv, fused=fuse,
+                                                     interpret=interp)
+        kw = dict(M=M, tol=cfg.tol, atol=cfg.atol, maxiter=cfg.maxiter)
         if cfg.method == "block_cg":
             single = b.ndim == 1
             B = b[None] if single else b
             X0 = None if x0 is None else (x0[None] if single else x0)
-            X, info = _solvers.block_cg(mv, B, X0, M=M, tol=cfg.tol,
-                                        atol=cfg.atol, maxiter=cfg.maxiter)
+            with span("krylov.block_cg"):
+                X, info = _solvers.block_cg(mv, B, X0, **kw)
             if single:
                 return X[0], _solvers.SolveInfo(info.iters, info.resnorm[0],
                                                 info.converged[0])
             return X, info
-        if cfg.method == "cg":
+        if cfg.method in ("cg", "bicgstab"):
             if fuse:
-                return _solvers.cg_fused(mv, b, x0, dinv=dinv, M=M,
-                                         tol=cfg.tol, atol=cfg.atol,
-                                         maxiter=cfg.maxiter,
-                                         interpret=interp)
-            return _solvers.cg(mv, b, x0, M=M, tol=cfg.tol, atol=cfg.atol,
-                               maxiter=cfg.maxiter)
-        if cfg.method == "bicgstab":
-            if fuse:
-                return _solvers.bicgstab_fused(mv, b, x0, dinv=dinv, M=M,
-                                               tol=cfg.tol, atol=cfg.atol,
-                                               maxiter=cfg.maxiter,
-                                               interpret=interp)
-            return _solvers.bicgstab(mv, b, x0, M=M, tol=cfg.tol,
-                                     atol=cfg.atol, maxiter=cfg.maxiter)
-        if cfg.method == "gmres":
-            return _solvers.gmres(mv, b, x0, M=M, tol=cfg.tol, atol=cfg.atol,
-                                  restart=cfg.restart,
-                                  maxiter=max(cfg.maxiter // cfg.restart, 1))
-        raise ValueError(
-            f"unknown method {cfg.method!r} for backend {cfg.backend!r}")
+                fn = getattr(_solvers, cfg.method + "_fused")
+                kw.update(dinv=dinv, interpret=interp)
+            else:
+                fn = getattr(_solvers, cfg.method)
+        elif cfg.method == "gmres":
+            fn = _solvers.gmres
+            kw.update(restart=cfg.restart,
+                      maxiter=max(cfg.maxiter // cfg.restart, 1))
+        else:
+            raise ValueError(
+                f"unknown method {cfg.method!r} for backend {cfg.backend!r}")
+        with span("krylov." + fn.__name__):
+            return fn(mv, b, x0, **kw)
 
     def transpose_plan(self, plan):
         """Adjoint plan sharing THIS plan's kernel layouts: the kernel plan
@@ -880,6 +866,7 @@ class SolverPlan:
     mesh = None          # jax.sharding.Mesh for dist-backed plans
     dmeta = None         # repro.core.distributed.DistMeta for dist plans
 
+    @spanned("plan.analyze")
     def __init__(self, cfg: SolverConfig, A: SparseTensor,
                  cache: Optional[dict] = None):
         if cfg.backend not in BACKENDS:
@@ -900,7 +887,7 @@ class SolverPlan:
         self._cache = cache if cache is not None else {cfg.plan_key(): self}
         self._tplan: Optional["SolverPlan"] = None
         self._setup_memo: dict = {}
-        PLAN_STATS["analyze"] += 1
+        count("analyze")
         # analyze is eager BY CONTRACT: plans outlive any single trace, so
         # artifact arrays built here must be concrete even when the first
         # solve happens inside jit/grad — a traced constant stored on the
@@ -913,7 +900,7 @@ class SolverPlan:
         """Per-values-array memo hit: identity of the array is the key."""
         hit = self._setup_memo.get(slot)
         if hit is not None and hit[0]() is key_array:
-            PLAN_STATS["setup_reuse"] += 1
+            count("setup_reuse")
             return hit[1]
         return None
 
@@ -950,6 +937,7 @@ class SolverPlan:
         if kernels:
             check_kernel_dtype(", ".join(kernels), dtype, default_interpret())
 
+    @spanned("plan.setup")
     def setup(self, A: SparseTensor):
         """Run (or reuse) the backend's values-dependent setup.
 
@@ -972,12 +960,13 @@ class SolverPlan:
             if hit is not None:
                 return hit
         self.check_dtype(A.val.dtype)
-        PLAN_STATS["setup"] += 1
+        count("setup")
         state = self.backend.setup(self, A)
         if self.backend.cache_setup:
             self._memo_store("state", A.val, state)
         return state
 
+    @spanned("plan.setup")
     def setup_batch(self, A: SparseTensor):
         """Batched setup over stacked values — ONE vmapped trace, memoized.
 
@@ -995,7 +984,7 @@ class SolverPlan:
             if hit is not None:
                 return hit
         self.check_dtype(val.dtype)
-        PLAN_STATS["setup"] += 1
+        count("setup")
         flat = val.reshape((-1, val.shape[-1]))
         state = jax.vmap(
             lambda v: self.backend.setup(self, self.matrix(v)))(flat)
@@ -1004,11 +993,15 @@ class SolverPlan:
         return state
 
     # -- stage ❸: solve ------------------------------------------------------
+    def _run(self, state, A: SparseTensor, b, x0, cfg: SolverConfig):
+        with span("plan.solve"):
+            return self.backend.solve(self, state, A, b, x0, cfg)
+
     def solve_single(self, A: SparseTensor, b, x0=None, state=None,
                      cfg: Optional[SolverConfig] = None):
         cfg = cfg if cfg is not None else self.cfg
         state = self.setup(A) if state is None else state
-        return self.backend.solve(self, state, A, b, x0, cfg)
+        return self._run(state, A, b, x0, cfg)
 
     def solve(self, A: SparseTensor, b, x0=None,
               cfg: Optional[SolverConfig] = None):
@@ -1017,7 +1010,7 @@ class SolverPlan:
         solve-loop knobs (tol/atol/maxiter/restart) without re-analyzing."""
         cfg = cfg if cfg is not None else self.cfg
         if self.backend.handles_batch:
-            return self.backend.solve(self, self.setup(A), A, b, x0, cfg)
+            return self._run(self.setup(A), A, b, x0, cfg)
         batch = jnp.broadcast_shapes(A.batch_shape, b.shape[:-1])
         if batch and not A.batch_shape:
             # multi-rhs on ONE matrix: a single setup (one factorization /
@@ -1030,11 +1023,11 @@ class SolverPlan:
                 # directions shared across right-hand sides
                 fx0 = None if x0 is None else jnp.broadcast_to(
                     x0, batch + x0.shape[-1:]).reshape(fb.shape)
-                xs, infos = self.backend.solve(self, state, A, fb, fx0, cfg)
+                xs, infos = self._run(state, A, fb, fx0, cfg)
                 return xs.reshape(batch + (b.shape[-1],)), infos
 
             def one(rhs, xx0=None):
-                return self.backend.solve(self, state, A, rhs, xx0, cfg)
+                return self._run(state, A, rhs, xx0, cfg)
 
             if x0 is None:
                 xs, infos = jax.vmap(lambda rhs: one(rhs))(fb)
@@ -1060,8 +1053,7 @@ class SolverPlan:
                 states = self.setup_batch(Ab)
 
                 def one(st, v, rhs, xx0=None):
-                    return self.backend.solve(self, st, self.matrix(v), rhs,
-                                              xx0, cfg)
+                    return self._run(st, self.matrix(v), rhs, xx0, cfg)
 
                 if fx0 is None:
                     xs, infos = jax.vmap(
@@ -1153,12 +1145,12 @@ class SolverPlan:
             return self._tplan
         n, m = self.shape
         if n == m and self.props.get("symmetric", False):
-            PLAN_STATS["transpose_shared"] += 1
+            count("transpose_shared")
             self._tplan = self
             return self
         tp = self.backend.transpose_plan(self)
         if tp is not None:
-            PLAN_STATS["transpose_shared"] += 1
+            count("transpose_shared")
             self._tplan = tp
             return tp
 
@@ -1194,6 +1186,7 @@ class SolverPlan:
                                    precond=self.cfg.precond)
 
 
+@spanned("plan.get")
 def get_plan(A: SparseTensor, cfg: Optional[SolverConfig] = None,
              **kw) -> SolverPlan:
     """Fetch (or analyze-and-cache) the plan for ``A``'s pattern + ``cfg``.
@@ -1216,9 +1209,9 @@ def get_plan(A: SparseTensor, cfg: Optional[SolverConfig] = None,
     key = cfg.plan_key() + (tuple(extra()) if extra is not None else ())
     plan = cache.get(key)
     if plan is not None:
-        PLAN_STATS["cache_hit"] += 1
+        count("cache_hit")
         return plan
-    PLAN_STATS["cache_miss"] += 1
+    count("cache_miss")
     plan = SolverPlan(cfg, A, cache=cache)
     cache[key] = plan
     return plan

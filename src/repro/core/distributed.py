@@ -45,6 +45,7 @@ from jax.sharding import AxisType, Mesh, NamedSharding, PartitionSpec as P
 from . import dispatch as _dispatch
 from . import solvers as _solvers
 from .sparse import SparseTensor
+from .spans import count, scoped, span
 
 __all__ = ["halo_exchange", "HaloProgram", "halo_program", "halo_apply",
            "DSparseTensor", "DSparseTensorList",
@@ -660,7 +661,7 @@ def _build_t_partition(cfg, plan, meta: DistMeta, bounds) -> dict:
     gather map from the forward ``lval`` layout so the adjoint derives the
     Aᵀ values without any per-call partitioning."""
     from .precond import DistPreconditionerPlan
-    _dispatch.PLAN_STATS["t_partition"] += 1
+    count("t_partition")
     p, nnz_loc = np.asarray(plan.row).shape
     row_g, col_g, fa = global_entries(plan.row, plan.col, meta, bounds)
 
@@ -825,7 +826,8 @@ def dist_solve(plan, state, A, b, x0, cfg):
     args = (A.lval, ell.src, ell.col, b) + ((x0,) if have_x0 else ()) + (
         jnp.asarray(cfg.tol, dt), jnp.asarray(cfg.atol, dt),
         jnp.asarray(cfg.maxiter, jnp.int32))
-    return programs[key](*(args + state))
+    with span("dist.solve"):
+        return programs[key](*(args + state))
 
 
 def assemble_matrix_grad(plan, lam, x):
@@ -934,7 +936,7 @@ def pipelined_cg(matvec: Callable, b: jax.Array, *, M: Callable = lambda r: r,
 
     st0 = (x, r, u, w, z, q, s, p, gamma, delta, one, jnp.asarray(0.0, b.dtype),
            jnp.array(0))
-    st = lax.while_loop(cond, body, st0)
+    st = lax.while_loop(cond, scoped("krylov.pipelined_cg", body), st0)
     x, r = st[0], st[1]
     rn = jnp.sqrt(psum(jnp.sum(r * r)))
     return x, _solvers.SolveInfo(st[-1], rn, rn <= target)

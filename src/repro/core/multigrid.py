@@ -41,6 +41,7 @@ import numpy as np
 from ..data.poisson import vc_coefficients
 from ..kernels.ref import stencil5_ref
 from .sparse import aggregate_pattern, coo_matvec, spgemm_program
+from .spans import count, span
 
 
 # ---------------------------------------------------------------------------
@@ -65,12 +66,17 @@ def v_cycle(levels: Tuple[Level, ...], b, level: int = 0):
     (static level count), every op inside is traced-safe."""
     lv = levels[level]
     if lv.coarse_solve is not None:
-        return lv.coarse_solve(b)
-    x = lv.smooth(jnp.zeros_like(b), b)
-    r = b - lv.matvec(x)
-    ec = v_cycle(levels, lv.restrict(r), level + 1)
-    x = x + lv.prolong(ec)
-    return (lv.post_smooth or lv.smooth)(x, b)
+        with jax.named_scope("mg.coarse"):
+            return lv.coarse_solve(b)
+    with jax.named_scope("mg.smooth"):
+        x = lv.smooth(jnp.zeros_like(b), b)
+    with jax.named_scope("mg.restrict"):
+        rc = lv.restrict(b - lv.matvec(x))
+    ec = v_cycle(levels, rc, level + 1)
+    with jax.named_scope("mg.prolong"):
+        x = x + lv.prolong(ec)
+    with jax.named_scope("mg.smooth"):
+        return (lv.post_smooth or lv.smooth)(x, b)
 
 
 # ---------------------------------------------------------------------------
@@ -295,8 +301,7 @@ def amg_symbolic(row, col, n: int, *, theta: float = 0.08,
     the cached-LDLᵀ machinery instead of a dense solve.
     """
     from . import direct as _direct
-    from .dispatch import PLAN_STATS
-    with jax.ensure_compile_time_eval():
+    with span("amg.coarsen"), jax.ensure_compile_time_eval():
         r = np.asarray(row, np.int64)
         c = np.asarray(col, np.int64)
         levels: List[AMGLevelSymbolic] = []
@@ -339,7 +344,7 @@ def amg_symbolic(row, col, n: int, *, theta: float = 0.08,
                 g2_dst=jnp.asarray(g2_dst, jnp.int32), nnz_c=len(c_row)))
             r, c, n_l = c_row, c_col, n_c
         coarse = _direct.symbolic_factor(r, c, n_l)
-        PLAN_STATS["coarsen"] += 1
+        count("coarsen")
         stats = {"n_levels": len(levels) + 1, "n_coarse": n_l,
                  "sizes": [lv.n for lv in levels] + [n_l]}
         return AMGArtifacts(levels=tuple(levels), coarse=coarse, n_coarse=n_l,
@@ -387,8 +392,7 @@ def amg_numeric(art: AMGArtifacts, val: jax.Array):
     values, and the coarsest level's numeric LDLᵀ/LU refactorization.
     Memoized per values array by ``SolverPlan.setup``."""
     from . import direct as _direct
-    from .dispatch import PLAN_STATS
-    PLAN_STATS["galerkin"] += 1
+    count("galerkin")
     state = []
     aval = val
     for lev in art.levels:

@@ -40,9 +40,10 @@ import numpy as np
 
 from . import dispatch as _dispatch
 from . import options as _options
-from .dispatch import PLAN_STATS, SolverConfig
+from .dispatch import SolverConfig
 from .solvers import SolveInfo
 from .sparse import SparseTensor, color_pattern, detect_properties
+from .spans import count
 
 __all__ = ["SparseNewton"]
 
@@ -137,7 +138,7 @@ class SparseNewton:
                     f"per assembly) > jac_coloring_budget ({budget}); pass "
                     f"assemble_jacobian= or raise the option "
                     f"(sla.set_options(jac_coloring_budget=...))")
-            PLAN_STATS["jac_color"] += 1
+            count("jac_color")
             self.n_colors = n_colors
             probes = np.zeros((n_colors, self.n))
             probes[color, np.arange(self.n)] = 1.0
@@ -152,7 +153,7 @@ class SparseNewton:
     def assemble(self, u, *theta):
         """Numeric Jacobian values on the declared pattern at ``u`` — one
         vmapped jvp sweep over the color probes (or the user callback)."""
-        PLAN_STATS["jac_assemble"] += 1
+        count("jac_assemble")
         if self.assemble_jacobian is not None:
             return self.assemble_jacobian(u, *theta)
         F = lambda x: self.residual(x, *theta)

@@ -37,6 +37,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from .spans import scoped, spanned
+
 __all__ = [
     "identity", "jacobi", "block_jacobi", "chebyshev",
     "PreconditionerPlan", "DistPreconditionerPlan", "make_preconditioner",
@@ -161,6 +163,7 @@ class PreconditionerPlan:
     apply closure consumed by the Krylov loops.
     """
 
+    @spanned("analyze.precond")
     def __init__(self, name: Optional[str], row, col, shape, *,
                  stencil=None, block: int = 128, degree: int = 8):
         self.name = "none" if name in (None, "none", "identity") else name
@@ -273,7 +276,11 @@ class PreconditionerPlan:
         run inside a per-instance ``vmap`` lane of a batched solve.  ``fused``
         routes multi-pass applies (Chebyshev) through the fused step kernels
         where they have one; it is a solve-time decision, never baked into
-        the state."""
+        the state.  The apply's ops carry the ``precond.apply`` scope."""
+        return scoped("precond.apply",
+                      self._apply(state, matvec, fused, interpret))
+
+    def _apply(self, state, matvec, fused, interpret) -> Callable:
         if self.name == "none":
             return identity()
         if self.name == "jacobi":

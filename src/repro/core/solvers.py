@@ -19,6 +19,8 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
+from .spans import scoped, span
+
 __all__ = [
     "SolveInfo", "SolveResult", "cg", "cg_fused", "bicgstab",
     "bicgstab_fused", "block_cg", "gmres", "cg_scan", "eigh_pinv_solve",
@@ -63,8 +65,9 @@ def as_solve_result(x, info: SolveInfo,
     """Wrap a backend's ``(x, SolveInfo)`` pair into a :class:`SolveResult`."""
     if reason is None:
         try:
-            reason = "converged" if bool(jnp.all(info.converged)) \
-                else "maxiter"
+            with span("solve.wait"):   # the host waits for the device here
+                done = bool(jnp.all(info.converged))
+            reason = "converged" if done else "maxiter"
         except Exception:      # traced under jit/vmap: not concretely known
             reason = "unknown"
     return SolveResult(x=x, iterations=info.iters, residual=info.resnorm,
@@ -140,7 +143,8 @@ def cg(matvec: Callable, b: jax.Array, x0: Optional[jax.Array] = None, *,
         p = z + (rz_new / rz) * p
         return (x, r, p, rz_new, k + 1)
 
-    x, r, p, rz, k = lax.while_loop(cond, body, (x0, r0, p0, rz0, jnp.array(0)))
+    x, r, p, rz, k = lax.while_loop(cond, scoped("krylov.cg", body),
+                                    (x0, r0, p0, rz0, jnp.array(0)))
     rn = jnp.sqrt(dot(r, r))
     return x, SolveInfo(k, rn, rn <= target)
 
@@ -188,7 +192,8 @@ def bicgstab(matvec: Callable, b: jax.Array, x0: Optional[jax.Array] = None, *,
     z = jnp.zeros_like(b)
     one = jnp.asarray(1.0, b.dtype)
     st0 = (x0, r0, r0, z, z, one, one, one, jnp.array(0), jnp.array(True))
-    x, r, *_, k, _ = lax.while_loop(cond, body, st0)
+    x, r, *_, k, _ = lax.while_loop(cond, scoped("krylov.bicgstab", body),
+                                    st0)
     rn = jnp.sqrt(dot(r, r))
     return x, SolveInfo(k, rn, rn <= target)
 
@@ -246,7 +251,8 @@ def cg_fused(matvec: Callable, b: jax.Array, x0: Optional[jax.Array] = None, *,
             return (x, r, p, s, rho_new, rr_new, alpha_new, k + 1)
 
         st0 = (x0, r0, p0, s0, rho0, rr0, alpha0, jnp.array(0))
-        x, r, p, s, rho, rr, alpha, k = lax.while_loop(cond, body, st0)
+        x, r, p, s, rho, rr, alpha, k = lax.while_loop(
+            cond, scoped("krylov.cg_fused", body), st0)
     else:
         z0 = M(r0)
         p0 = z0
@@ -268,7 +274,8 @@ def cg_fused(matvec: Callable, b: jax.Array, x0: Optional[jax.Array] = None, *,
             return (x, r, p, rz_new, rr_new, k + 1)
 
         st0 = (x0, r0, p0, rz0, rr0, jnp.array(0))
-        x, r, p, rz, rr, k = lax.while_loop(cond, body, st0)
+        x, r, p, rz, rr, k = lax.while_loop(
+            cond, scoped("krylov.cg_fused", body), st0)
 
     rn = jnp.sqrt(rr)
     return x, SolveInfo(k, rn, rn <= target)
@@ -336,7 +343,8 @@ def bicgstab_fused(matvec: Callable, b: jax.Array,
     one = jnp.asarray(1.0, b.dtype)
     st0 = (x0, r0, r0, z, z, one, rr0, one, one, rr0, jnp.array(0),
            jnp.array(True))
-    x, r, *_, rr, k, _ = lax.while_loop(cond, body, st0)
+    x, r, *_, rr, k, _ = lax.while_loop(
+        cond, scoped("krylov.bicgstab_fused", body), st0)
     rn = jnp.sqrt(rr)
     return x, SolveInfo(k, rn, rn <= target)
 
@@ -401,7 +409,8 @@ def block_cg(matvec: Callable, B: jax.Array,
         return (X, R, P, rho_new, it + 1)
 
     X, R, P, rho, it = lax.while_loop(
-        cond, body, (X0, R0, Z0, rho0, jnp.array(0)))
+        cond, scoped("krylov.block_cg", body),
+        (X0, R0, Z0, rho0, jnp.array(0)))
     rn = jnp.linalg.norm(R, axis=1)
     return X, SolveInfo(it, rn, rn <= target)
 
@@ -464,7 +473,8 @@ def gmres(matvec: Callable, b: jax.Array, x0: Optional[jax.Array] = None, *,
         return (x, r, jnp.linalg.norm(r), k + 1)
 
     x, r, rn, k = lax.while_loop(
-        cond, body, (x0, r0, jnp.linalg.norm(r0), jnp.array(0)))
+        cond, scoped("krylov.gmres", body),
+        (x0, r0, jnp.linalg.norm(r0), jnp.array(0)))
     return x, SolveInfo(k * m, rn, rn <= target)
 
 

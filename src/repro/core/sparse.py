@@ -59,17 +59,18 @@ def coo_matvec(val: jax.Array, row: jax.Array, col: jax.Array, x: jax.Array,
     still correct).  This is the ``jnp`` backend's SpMV and the oracle for the
     Pallas kernels.
     """
-    if val.ndim == 1 and x.ndim == 1:
-        return jax.ops.segment_sum(val * x[col], row, num_segments=n_rows)
-    # broadcast batch dims: val (..., nnz), x (..., n)
-    batch_shape = jnp.broadcast_shapes(val.shape[:-1], x.shape[:-1])
-    val = jnp.broadcast_to(val, batch_shape + val.shape[-1:])
-    x = jnp.broadcast_to(x, batch_shape + x.shape[-1:])
-    flat_v = val.reshape((-1, val.shape[-1]))
-    flat_x = x.reshape((-1, x.shape[-1]))
-    y = jax.vmap(lambda v, xx: jax.ops.segment_sum(v * xx[col], row,
-                                                   num_segments=n_rows))(flat_v, flat_x)
-    return y.reshape(batch_shape + (n_rows,))
+    with jax.named_scope("spmv.segment_sum"):
+        if val.ndim == 1 and x.ndim == 1:
+            return jax.ops.segment_sum(val * x[col], row, num_segments=n_rows)
+        # broadcast batch dims: val (..., nnz), x (..., n)
+        batch_shape = jnp.broadcast_shapes(val.shape[:-1], x.shape[:-1])
+        val = jnp.broadcast_to(val, batch_shape + val.shape[-1:])
+        x = jnp.broadcast_to(x, batch_shape + x.shape[-1:])
+        flat_v = val.reshape((-1, val.shape[-1]))
+        flat_x = x.reshape((-1, x.shape[-1]))
+        y = jax.vmap(lambda v, xx: jax.ops.segment_sum(
+            v * xx[col], row, num_segments=n_rows))(flat_v, flat_x)
+        return y.reshape(batch_shape + (n_rows,))
 
 
 def backward_error(val, row, col, n_rows: int, x: jax.Array, b: jax.Array,

@@ -48,9 +48,10 @@ def bell_matvec(meta: BellMeta, block_cols: jax.Array, perm: jax.Array,
                 interpret: bool = None) -> jax.Array:
     """``interpret=None`` resolves to the platform default; the plan engine
     threads its analyze-time flag through here (kernel plans)."""
-    bv = bell_assemble(meta, perm, val)
-    y = bell_spmv_pallas(meta, block_cols, bv, x, interpret)
-    return y[:n]
+    with jax.named_scope("spmv.bell"):
+        bv = bell_assemble(meta, perm, val)
+        y = bell_spmv_pallas(meta, block_cols, bv, x, interpret)
+        return y[:n]
 
 
 def _bell_mv_fwd(meta, block_cols, perm, val, x, n, interpret):
@@ -94,10 +95,13 @@ def bell_matvec_ref(meta: BellMeta, block_cols: jax.Array, perm: jax.Array,
 @functools.partial(jax.custom_vjp, nondiff_argnums=(0,))
 def stencil5_matvec(meta: Stencil5Meta, val: jax.Array, x: jax.Array) -> jax.Array:
     """``val``: (5·nx·ny,) flattened signed planes; ``x``: (nx·ny,)."""
-    v5 = val.reshape(5, meta.nx, meta.ny)
-    x2 = x.reshape(meta.nx, meta.ny)
-    y = stencil5_pallas(meta, v5, x2, _interpret())
-    return y.reshape(meta.nx * meta.ny)
+    # the scope stays outside the kernel's jit: its op keeps the name
+    # ``stencil5_pallas.N``
+    with jax.named_scope("spmv.stencil"):
+        v5 = val.reshape(5, meta.nx, meta.ny)
+        x2 = x.reshape(meta.nx, meta.ny)
+        y = stencil5_pallas(meta, v5, x2, _interpret())
+        return y.reshape(meta.nx * meta.ny)
 
 
 def _stencil_transpose_planes(v5: jax.Array) -> jax.Array:

@@ -74,8 +74,13 @@ def _make_kernel(body, n_in: int, n_sc: int, n_out: int, n_dots: int):
     return kernel
 
 
-def _run(body, vecs, scalars, n_out: int, n_dots: int, interpret):
+def _run(name: str, body, vecs, scalars, n_out: int, n_dots: int,
+         interpret):
     """Launch one fused vector pass.
+
+    ``name`` is the kernel's Python name: the launch runs under a
+    ``jax.named_scope`` of it, so the compiled custom call is ``name.N`` in
+    a profile (not the enclosing loop body's name).
 
     ``vecs``: n-length arrays, tiled to (nb, 8, 128) blocks (zero-padded —
     every body below maps pad zeros to zeros, so dots are exact); ``scalars``:
@@ -105,14 +110,16 @@ def _run(body, vecs, scalars, n_out: int, n_dots: int, interpret):
         out_specs.append(pl.BlockSpec((1, n_dots), lambda i: (I0, I0),
                                       memory_space=pltpu.SMEM))
         out_shape.append(jax.ShapeDtypeStruct((1, n_dots), dtype))
-    res = pl.pallas_call(
-        _make_kernel(body, n_in, n_sc, n_out, n_dots),
-        grid=(nb,),
-        in_specs=in_specs,
-        out_specs=out_specs,
-        out_shape=out_shape,
-        interpret=interpret,
-    )(*args)
+    with jax.named_scope(name):
+        res = pl.pallas_call(
+            _make_kernel(body, n_in, n_sc, n_out, n_dots),
+            grid=(nb,),
+            in_specs=in_specs,
+            out_specs=out_specs,
+            out_shape=out_shape,
+            interpret=interpret,
+            name=name,
+        )(*args)
     outs = tuple(r.reshape(nb * BLK)[:n] for r in res[:n_out])
     dots = tuple(res[n_out][0, j] for j in range(n_dots)) if n_dots else ()
     return outs + dots
@@ -134,7 +141,8 @@ def fused_cg_update(x, r, p, s, dinv, alpha, *, interpret=None):
         rn = r_ - a * s_
         zn = d_ * rn
         return (xn, rn, zn), (jnp.sum(rn * zn), jnp.sum(rn * rn))
-    return _run(body, (x, r, p, s, dinv), (alpha,), 3, 2, interpret)
+    return _run("fused_cg_update", body, (x, r, p, s, dinv), (alpha,), 3, 2,
+                interpret)
 
 
 fused_cg_update.passes = (5, 3)
@@ -149,7 +157,8 @@ def fused_cg_direction(z, w, p, s, beta, *, interpret=None):
         z_, w_, p_, s_ = v
         (b,) = sc
         return (z_ + b * p_, w_ + b * s_), (jnp.sum(w_ * z_),)
-    return _run(body, (z, w, p, s), (beta,), 2, 1, interpret)
+    return _run("fused_cg_direction", body, (z, w, p, s), (beta,), 2, 1,
+                interpret)
 
 
 fused_cg_direction.passes = (4, 2)
@@ -164,7 +173,8 @@ def fused_cg_halfstep(x, r, p, s, alpha, *, interpret=None):
         xn = x_ + a * p_
         rn = r_ - a * s_
         return (xn, rn), (jnp.sum(rn * rn),)
-    return _run(body, (x, r, p, s), (alpha,), 2, 1, interpret)
+    return _run("fused_cg_halfstep", body, (x, r, p, s), (alpha,), 2, 1,
+                interpret)
 
 
 fused_cg_halfstep.passes = (4, 2)
@@ -179,7 +189,8 @@ def fused_cheb_step(x, dk, rk, c1, c2, *, interpret=None):
         a, b = sc
         dn = a * d_ + b * r_
         return (x_ + dn, dn), ()
-    return _run(body, (x, dk, rk), (c1, c2), 2, 0, interpret)
+    return _run("fused_cheb_step", body, (x, dk, rk), (c1, c2), 2, 0,
+                interpret)
 
 
 fused_cheb_step.passes = (3, 2)
@@ -191,7 +202,7 @@ def fused_dots2(u, v, *, interpret=None):
     def body(vv, sc):
         u_, v_ = vv
         return (), (jnp.sum(u_ * v_), jnp.sum(u_ * u_))
-    return _run(body, (u, v), (), 0, 2, interpret)
+    return _run("fused_dots2", body, (u, v), (), 0, 2, interpret)
 
 
 fused_dots2.passes = (2, 0)
@@ -209,7 +220,8 @@ def fused_bicg_p(r, p, v, dinv, beta, omega, restart, *, interpret=None):
         b, w, rs = sc
         pn = jnp.where(rs != 0, r_, r_ + b * (p_ - w * v_))
         return (pn, d_ * pn), ()
-    return _run(body, (r, p, v, dinv), (beta, omega, restart), 2, 0, interpret)
+    return _run("fused_bicg_p", body, (r, p, v, dinv), (beta, omega, restart),
+                2, 0, interpret)
 
 
 fused_bicg_p.passes = (4, 2)
@@ -222,7 +234,8 @@ def fused_bicg_s(r, v, dinv, alpha, *, interpret=None):
         (a,) = sc
         sn = r_ - a * v_
         return (sn, d_ * sn), ()
-    return _run(body, (r, v, dinv), (alpha,), 2, 0, interpret)
+    return _run("fused_bicg_s", body, (r, v, dinv), (alpha,), 2, 0,
+                interpret)
 
 
 fused_bicg_s.passes = (3, 2)
@@ -239,8 +252,8 @@ def fused_bicg_tail(x, s, t, phat, shat, rhat, alpha, omega, *, interpret=None):
         xn = x_ + a * ph_ + w * sh_
         rn = s_ - w * t_
         return (xn, rn), (jnp.sum(rh_ * rn), jnp.sum(rn * rn))
-    return _run(body, (x, s, t, phat, shat, rhat), (alpha, omega), 2, 2,
-                interpret)
+    return _run("fused_bicg_tail", body, (x, s, t, phat, shat, rhat),
+                (alpha, omega), 2, 2, interpret)
 
 
 fused_bicg_tail.passes = (6, 2)

@@ -33,6 +33,7 @@ import numpy as np
 from ..core import dispatch as _dispatch
 from ..core.dispatch import PLAN_STATS, SolverConfig, make_config
 from ..core.solvers import SolveInfo, SolveResult, as_solve_result
+from ..core.spans import spanned
 from ..core.sparse import SparseTensor
 
 
@@ -87,6 +88,7 @@ class SolveServer:
             self._jits[key] = fn
         return fn
 
+    @spanned("sla.serve_batch")
     def submit_batch(self, requests: List[SolveRequest]) -> List[SolveResult]:
         """Solve a wave of requests; results come back in request order.
 

@@ -46,6 +46,8 @@ from .core.options import Options
 from .core.options import current as get_options
 from .core.options import options, set_options
 from .core.solvers import SolveInfo, SolveResult, as_solve_result
+from .core import spans as _spans
+from .core.spans import span
 from .core.sparse import SparseTensor
 
 __all__ = [
@@ -69,6 +71,7 @@ __all__ = [
     "SolveServer",
     "PLAN_STATS",
     "reset_plan_stats",
+    "solve_records",
 ]
 
 # lazily bound: the distributed layer pulls in mesh/shard_map machinery and
@@ -109,7 +112,8 @@ def solve(A, b, **kw):
     ``atol``, ``maxiter``, ``x0``.  Returns ``x`` only; gradients flow
     through the O(1)-graph adjoint solve.  Use :func:`solve_with_info` for
     convergence diagnostics."""
-    return A.solve(b, **kw)
+    with span("sla.solve"):
+        return A.solve(b, **kw)
 
 
 def solve_with_info(A, b, *, x0=None, **kw) -> SolveResult:
@@ -121,10 +125,35 @@ def solve_with_info(A, b, *, x0=None, **kw) -> SolveResult:
     "maxiter", or "unknown" under a trace).  This entry point is
     un-differentiated — it is the serving/diagnostics path; use
     :func:`solve` when gradients matter."""
-    if getattr(A, "mesh", None) is not None:      # distributed tensor
-        x, info = A.solve_with_info(b, x0=x0, **kw)
-    else:
-        from .core.dispatch import make_config, solve_impl
-        cfg = make_config(A, **kw)
-        x, info = solve_impl(cfg, A, b, x0)
-    return as_solve_result(x, info)
+    with span("sla.solve"):
+        if getattr(A, "mesh", None) is not None:      # distributed tensor
+            x, info = A.solve_with_info(b, x0=x0, **kw)
+        else:
+            from .core.dispatch import make_config, solve_impl
+            cfg = make_config(A, **kw)
+            x, info = solve_impl(cfg, A, b, x0)
+        return as_solve_result(x, info)
+
+
+def solve_records(n=None) -> list:
+    """The per-solve view for operators: the newest ``n`` completed solve
+    records (all that are kept, up to 1024, when ``n`` is None), oldest
+    first, as plain dicts of numbers and strings.
+
+    Every :func:`solve`, :func:`solve_with_info` and
+    ``SolveServer.submit_batch`` call leaves one record, named after its
+    outermost span (``"sla.solve"``, ``"sla.serve_batch"``; a bare
+    :func:`get_plan` leaves a ``"plan.get"`` record).  A record holds the
+    call's host seconds (``seconds``), the inclusive and self seconds of
+    each stage span inside it (``incl_s``, ``self_s``: ``plan.get``,
+    ``plan.analyze``, ``plan.setup``, ``precond.refresh``, ``plan.solve``,
+    ``krylov.<method>``, ``solve.wait``, ...), the ``PLAN_STATS`` counters
+    it moved (``counters``, with JAX's ``jax_traces``, ``jax_lowerings``,
+    ``jax_compiles`` and ``jax_cache_hits``), the names of the programs it
+    lowered (``lowered``) and whether it ran under a JAX trace (``traced``:
+    its times are then trace-time times).  Read it to see where a slow
+    solve spent its host time: ``seconds - incl_s["solve.wait"]`` is the
+    time outside the wait for the device, and ``jax_lowerings > 0`` on a
+    warm solve means a program was built again.  ``reset_plan_stats()``
+    clears the records."""
+    return _spans.solve_records(n)

@@ -38,6 +38,7 @@ EXPECTED_SURFACE = sorted([
     "serve",
     "set_options",
     "solve",
+    "solve_records",
     "solve_with_info",
 ])
 

@@ -129,3 +129,31 @@ def test_block_trsv_compiles(shape, k, wb, rb, mode):
         D, y, w, m, mode=mode, pairs=True, interpret=False),
         shape(k, wb, wb), shape(k, wb), shape(k, dtype=I32),
         shape(k, wb, dtype=jnp.bool_))
+
+
+def test_loop_kernels_are_named_after_themselves(shape, monkeypatch):
+    """Inside the fused MG-style CG loop (a ``while`` body under the
+    ``krylov.cg_fused`` scope) the half-step kernel's custom call is named
+    ``fused_cg_halfstep.N``, not after the loop body, and the stencil
+    kernel's stays ``stencil5_pallas.N`` under the ``spmv.stencil`` scope."""
+    import re
+
+    from repro.core.solvers import cg_fused
+    from repro.kernels import ops
+    from repro.kernels.ops import stencil5_matvec
+    monkeypatch.setattr(ops, "_interpret", lambda: False)  # compile for v5e
+    ng = 256
+    meta = Stencil5Meta(nx=ng, ny=ng)
+
+    def solve(val, b):
+        x, _ = cg_fused(lambda v: stencil5_matvec(meta, val, v), b,
+                        M=lambda r: 0.25 * r, maxiter=3, interpret=False)
+        return x
+
+    text = _compile(solve, shape(5 * ng * ng), shape(ng * ng))
+    calls = re.findall(r"%([\w.\-]+) = [^\n]*custom_call_target=\"tpu_custom_"
+                       r"call\"", text)
+    assert any(re.fullmatch(r"fused_cg_halfstep\.\d+", c) for c in calls), calls
+    assert any(re.fullmatch(r"stencil5_pallas\.\d+", c) for c in calls), calls
+    assert " while(" in text
+    assert not any(c.startswith("body") for c in calls), calls

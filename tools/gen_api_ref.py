@@ -83,7 +83,7 @@ def render() -> str:
         ("Serving",
          ["serve", "SolveServer"]),
         ("Introspection",
-         ["PLAN_STATS", "reset_plan_stats"]),
+         ["PLAN_STATS", "reset_plan_stats", "solve_records"]),
     ]
     grouped = {n for _, names in groups for n in names}
     missing = sorted(set(sla.__all__) - grouped)
