@@ -59,6 +59,7 @@ assert reuse; ``register_backend`` adds custom backends either as a
 from __future__ import annotations
 
 import collections
+import copy
 import dataclasses
 import sys
 import types
@@ -271,6 +272,70 @@ def _plan_matvec(plan: "SolverPlan", kp: KernelPlan, val) -> Callable:
                                           interp)
     row, col = plan.row, plan.col
     return lambda x: coo_matvec(val, row, col, x, n)
+
+
+class _Slot:
+    """Stand-in for the ``i``-th array of a plan's lifted arrays."""
+    __slots__ = ("i",)
+
+    def __init__(self, i: int):
+        self.i = i
+
+
+def _map_leaves(obj, leaf: type, fn: Callable):
+    """``obj`` with every ``leaf`` instance inside it replaced by
+    ``fn(leaf)``.  Tuples, named tuples, lists, dicts and plain objects are
+    rebuilt (objects by shallow copy) where something inside them changed,
+    and come back as the same object where nothing did."""
+    if isinstance(obj, leaf):
+        return fn(obj)
+    if isinstance(obj, (tuple, list)):
+        parts = [_map_leaves(v, leaf, fn) for v in obj]
+        if all(p is v for p, v in zip(parts, obj)):
+            return obj
+        return type(obj)._make(parts) if hasattr(obj, "_fields") \
+            else type(obj)(parts)
+    if isinstance(obj, dict):
+        parts = {k: _map_leaves(v, leaf, fn) for k, v in obj.items()}
+        if all(parts[k] is v for k, v in obj.items()):
+            return obj
+        return parts
+    if hasattr(obj, "__dict__") and not callable(obj):
+        parts = {k: _map_leaves(v, leaf, fn) for k, v in vars(obj).items()}
+        if all(parts[k] is v for k, v in vars(obj).items()):
+            return obj
+        new = copy.copy(obj)
+        vars(new).update(parts)
+        return new
+    return obj
+
+
+def _plan_arrays(plan: "SolverPlan"):
+    """``(skeleton, arrays)``: the plan attributes the solve stage reads
+    (pattern, BELL layout, analyze artifacts) with each array replaced by a
+    :class:`_Slot` into ``arrays``, so the solve program takes them as
+    arguments.  Arrays a jitted function closes over are written into its
+    program as literals: an AMG hierarchy's index programs or a segment-sum
+    pattern would be lowered as megabytes of constants.  Cached on the
+    plan, except where an array is a tracer (a pattern traced with its
+    tensor): such a plan lives only as long as its trace."""
+    if plan._program_args is not None:
+        return plan._program_args
+    arrays, slots = [], {}
+
+    def lift(a):
+        if id(a) not in slots:
+            slots[id(a)] = _Slot(len(arrays))
+            arrays.append(a)
+        return slots[id(a)]
+
+    view = {"row": plan.row, "col": plan.col, "bell": plan.bell,
+            "artifacts": {k: v for k, v in plan.artifacts.items()
+                          if k != "programs"}}
+    lifted = (_map_leaves(view, jax.Array, lift), tuple(arrays))
+    if not any(isinstance(a, jax.core.Tracer) for a in arrays):
+        plan._program_args = lifted
+    return lifted
 
 
 @dataclasses.dataclass(frozen=True)
@@ -558,19 +623,60 @@ class IterativeBackend(Backend):
         return A.val, pstate, dinv
 
     def solve(self, plan, state, A, b, x0, cfg):
+        program, args = self.solve_program(plan, state, b, x0, cfg)
+        return program(*args)
+
+    def solve_program(self, plan, state, b, x0, cfg):
+        """The solve stage as ``(program, args)``; ``program(*args)`` returns
+        ``(x, SolveInfo)``.
+
+        ``program`` is one ``jax.jit`` function per plan and key (method,
+        fuse flag, interpret flag, warm start, gmres restart), kept in
+        ``plan.artifacts["programs"]``: it builds the matvec and the
+        preconditioner apply from the state and runs the Krylov call,
+        prologue included, so a warm solve dispatches one executable and
+        traces nothing.  Every array it reads enters as an argument — the
+        setup state, ``b``, ``x0``, the plan's analyze-time index arrays
+        (:func:`_plan_arrays`) — and tol, atol and maxiter are traced
+        scalars, so a tolerance sweep reuses the program.  Under an outer
+        trace the call inlines."""
+        kp = plan.artifacts.get("kernel")
+        fuse = _fuse_enabled(kp)
+        interp = kp.interpret if kp is not None else None
+        method = cfg.method
+        if method not in ("cg", "bicgstab", "gmres", "block_cg"):
+            raise ValueError(
+                f"unknown method {method!r} for backend {cfg.backend!r}")
+        restart = cfg.restart if method == "gmres" else None
+        maxiter = max(cfg.maxiter // restart, 1) if restart else cfg.maxiter
+        loop = (float(cfg.tol), float(cfg.atol), int(maxiter))
+        skeleton, arrays = _plan_arrays(plan)
+        programs = plan.artifacts.setdefault("programs", {})
+        key = (method, fuse, interp, x0 is not None, restart)
+        if key not in programs:
+            def run(arrays, *args):
+                count("solve_program_build")
+                view = copy.copy(plan)
+                vars(view).update(_map_leaves(
+                    skeleton, _Slot, lambda s: arrays[s.i]))
+                return self._stage(view, method, fuse, interp, restart, *args)
+
+            programs[key] = jax.jit(run)
+        count("solve_program_call")
+        return programs[key], (arrays, state, b, x0) + loop
+
+    def _stage(self, plan, method, fuse, interp, restart, state, b, x0, tol,
+               atol, maxiter):
         val, pstate, dinv = state
         # rebuild from the STATE's values, not A.val: transpose plans remap
         # the forward values in setup (_StencilTransposeBackend) and batched
         # solves feed per-lane state slices
         mv = self._matvec_from_val(plan, val)
-        kp = plan.artifacts.get("kernel")
-        fuse = _fuse_enabled(kp)
-        interp = kp.interpret if kp is not None else None
         with span("precond.make_apply"):
             M = plan.artifacts["precond"].make_apply(pstate, mv, fused=fuse,
                                                      interpret=interp)
-        kw = dict(M=M, tol=cfg.tol, atol=cfg.atol, maxiter=cfg.maxiter)
-        if cfg.method == "block_cg":
+        kw = dict(M=M, tol=tol, atol=atol, maxiter=maxiter)
+        if method == "block_cg":
             single = b.ndim == 1
             B = b[None] if single else b
             X0 = None if x0 is None else (x0[None] if single else x0)
@@ -580,19 +686,14 @@ class IterativeBackend(Backend):
                 return X[0], _solvers.SolveInfo(info.iters, info.resnorm[0],
                                                 info.converged[0])
             return X, info
-        if cfg.method in ("cg", "bicgstab"):
-            if fuse:
-                fn = getattr(_solvers, cfg.method + "_fused")
-                kw.update(dinv=dinv, interpret=interp)
-            else:
-                fn = getattr(_solvers, cfg.method)
-        elif cfg.method == "gmres":
+        if method == "gmres":
             fn = _solvers.gmres
-            kw.update(restart=cfg.restart,
-                      maxiter=max(cfg.maxiter // cfg.restart, 1))
+            kw.update(restart=restart)
+        elif fuse:
+            fn = getattr(_solvers, method + "_fused")
+            kw.update(dinv=dinv, interpret=interp)
         else:
-            raise ValueError(
-                f"unknown method {cfg.method!r} for backend {cfg.backend!r}")
+            fn = getattr(_solvers, method)
         with span("krylov." + fn.__name__):
             return fn(mv, b, x0, **kw)
 
@@ -865,6 +966,7 @@ class SolverPlan:
 
     mesh = None          # jax.sharding.Mesh for dist-backed plans
     dmeta = None         # repro.core.distributed.DistMeta for dist plans
+    _program_args = None  # the solve program's lifted arrays (_plan_arrays)
 
     @spanned("plan.analyze")
     def __init__(self, cfg: SolverConfig, A: SparseTensor,
@@ -1086,8 +1188,8 @@ class SolverPlan:
 
         def visit(obj):
             nonlocal total
-            if obj is None or isinstance(obj, (int, float, bool, str, bytes,
-                                               complex)):
+            if obj is None or callable(obj) or isinstance(
+                    obj, (int, float, bool, str, bytes, complex)):
                 return
             if id(obj) in seen:
                 return

@@ -61,6 +61,8 @@ PLAN_STATS: Dict[str, int] = {
     "evictions": 0,        # plans dropped by the bounded LRU plan cache
     "jac_color": 0,        # Jacobian pattern colorings (once per SparseNewton)
     "jac_assemble": 0,     # numeric Jacobian assemblies (jvp probe sweeps)
+    "solve_program_build": 0,  # single-device solve programs traced
+    "solve_program_call": 0,   # calls of a plan's cached solve program
     "jax_traces": 0,       # jaxprs traced by JAX (counted once a span opened)
     "jax_lowerings": 0,    # programs lowered to MLIR
     "jax_compiles": 0,     # backend compiles

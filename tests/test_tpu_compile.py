@@ -157,3 +157,41 @@ def test_loop_kernels_are_named_after_themselves(shape, monkeypatch):
     assert any(re.fullmatch(r"stencil5_pallas\.\d+", c) for c in calls), calls
     assert " while(" in text
     assert not any(c.startswith("body") for c in calls), calls
+
+
+def test_plan_solve_program_compiles(shape, monkeypatch):
+    """The plan engine's whole MG-CG solve program for a stencil plan — the
+    V-cycle prologue, the fused loop and its kernels in one ``jax.jit`` —
+    compiles for a v5e from its argument shapes, the plan's arrays among
+    them, with the kernels keeping their names.  x64 off, as on the chip:
+    under x64 the MG hierarchy's dense coarse operator is float64."""
+    monkeypatch.setattr(fk, "default_interpret", lambda: False)
+    with jax.enable_x64(False):
+        _compile_plan_program(shape)
+
+
+def _compile_plan_program(shape):
+    import re
+
+    import numpy as np
+
+    from repro.core import get_plan, make_config
+    from repro.data.poisson import poisson2d_vc
+    ng = 256
+    kappa = np.exp(0.3 * np.random.default_rng(0).normal(size=(ng, ng)))
+    A = poisson2d_vc(jnp.asarray(kappa, F32), use_stencil_kernel=True)
+    A = A.with_values(A.val.astype(F32))
+    cfg = make_config(A, precond="mg")
+    plan = get_plan(A, cfg)
+    state = plan.setup(A)
+    program, args = plan.backend.solve_program(
+        plan, state, jnp.ones(ng * ng, F32), None, cfg)
+    abstract = jax.tree_util.tree_map(
+        lambda a: shape(*a.shape, dtype=a.dtype)
+        if isinstance(a, jax.Array) else a, args)
+    text = program.lower(*abstract).compile().as_text()
+    calls = re.findall(r"%([\w.\-]+) = [^\n]*custom_call_target=\"tpu_custom_"
+                       r"call\"", text)
+    assert any(re.fullmatch(r"fused_cg_halfstep\.\d+", c) for c in calls), calls
+    assert any(re.fullmatch(r"stencil5_pallas\.\d+", c) for c in calls), calls
+    assert " while(" in text
