@@ -262,8 +262,8 @@ def _plan_matvec(plan: "SolverPlan", kp: KernelPlan, val) -> Callable:
     """Single-instance matvec closure through the kernel plan's choice."""
     n = plan.shape[0]
     if kp.choice == "stencil" and plan.stencil is not None:
-        from ..kernels import ops as kops
-        return lambda x: kops.stencil5_matvec(plan.stencil, val, x)
+        fn = _stencil_matvec(plan.stencil)
+        return lambda x: fn(val, x)
     if kp.choice == "bell" and kp.bell is not None:
         from ..kernels import ops as kops
         meta, block_cols, perm = kp.bell
@@ -378,11 +378,32 @@ def _select_kernel(A: SparseTensor, backend: Optional[str] = None) -> str:
     return "coo"
 
 
+def _stencil_matvec(meta) -> Callable:
+    """The stencil kernel's SpMV as a function of (val, x): the layout's
+    metadata picks the 2-D 5-point or the 3-D 27-point kernel."""
+    from ..kernels import ops as kops
+    from ..kernels.stencil27 import Stencil27Meta
+    if isinstance(meta, Stencil27Meta):
+        return partial(kops.stencil27_matvec, meta)
+    return partial(kops.stencil5_matvec, meta)
+
+
+def _kernel_values(plan, val):
+    """Values as the solve stage holds them: the 3-D stencil kernel's
+    planes are laid out once here, in setup, not on every SpMV."""
+    kp = plan.artifacts.get("kernel")
+    if kp is not None and kp.choice == "stencil":
+        from ..kernels.ops import stencil27_planes
+        from ..kernels.stencil27 import Stencil27Meta
+        if isinstance(plan.stencil, Stencil27Meta):
+            return stencil27_planes(plan.stencil, val)
+    return val
+
+
 def _kernel_fn(A: SparseTensor, kernel: str) -> Callable:
     """Single-instance SpMV as a function of (val, x) — vmap-able."""
     if kernel == "stencil" and A.stencil is not None:
-        from ..kernels import ops as kops
-        return partial(kops.stencil5_matvec, A.stencil)
+        return _stencil_matvec(A.stencil)
     if kernel == "bell" and A.bell is not None:
         from ..kernels import ops as kops
         meta, block_cols, perm = A.bell
@@ -607,20 +628,22 @@ class IterativeBackend(Backend):
         """Values-dependent setup as an ARRAYS-ONLY pytree.
 
         Returns ``(val, pstate, dinv)`` — the (possibly transpose-remapped)
-        values, the preconditioner's refresh_state pytree (block inverses,
-        spectrum bounds, MG/AMG hierarchy arrays, ILU factors), and the
+        values in the kernel's layout (:func:`_kernel_values`), the
+        preconditioner's refresh_state pytree (block inverses, spectrum
+        bounds, MG/AMG hierarchy arrays, ILU factors), and the
         diagonal-inverse vector for the fused step kernels (None when the
         apply is not a diagonal scale).  No closures: a stacked batch of
         shared-pattern instances runs ONE ``jax.vmap`` of this method
         (:meth:`SolverPlan.setup_batch`) and the solve stage rebuilds the
         matvec/apply closures per lane.  The fuse decision itself stays a
         solve-time read of ``options.fused_step``."""
-        mv = self._matvec_from_val(plan, A.val)
+        val = _kernel_values(plan, A.val)
+        mv = self._matvec_from_val(plan, val)
         pre = plan.artifacts["precond"]
         with span("precond.refresh"):
-            pstate = pre.refresh_state(A, mv)
+            pstate = pre.refresh_state(A, mv, val)
         dinv = pre.fused_diag(A)
-        return A.val, pstate, dinv
+        return val, pstate, dinv
 
     def solve(self, plan, state, A, b, x0, cfg):
         program, args = self.solve_program(plan, state, b, x0, cfg)
@@ -768,8 +791,9 @@ class StencilBackend(IterativeBackend):
         then runs the ordinary stencil setup — the same kernel, the same
         preconditioner machinery (``precond='mg'`` included), zero
         re-analysis."""
+        from ..kernels.stencil5 import Stencil5Meta
         meta = plan.stencil
-        if meta is None or meta.nx != meta.ny:
+        if not isinstance(meta, Stencil5Meta) or meta.nx != meta.ny:
             return None
         ng = meta.nx
         if plan.shape != (ng * ng, ng * ng):

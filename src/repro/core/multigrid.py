@@ -5,10 +5,15 @@ Jacobi preconditioning, "insufficient at large DOF — hence the 1e-2
 residuals in our multi-GPU runs"; AMG (AmgX/hypre) is named as future work.
 This module closes that gap twice over:
 
-* **Geometric** (``precond="mg"``, stencil operators): matrix-free V-cycle —
-  weighted-Jacobi smoothing, full-weighting restriction of both residual and
-  coefficient field, bilinear prolongation, dense coarse solve.  TPU-friendly:
-  shifts, pooling and small matmuls only.
+* **Geometric** (``precond="mg"``, stencil operators), matrix-free, with
+  one builder per stencil layout.  On 2-D 5-point planes: weighted-Jacobi
+  smoothing, full-weighting restriction of both residual and coefficient
+  field, bilinear prolongation, dense coarse solve — shifts, pooling and
+  small matmuls only.  On 3-D 27-point planes: HPCG's ``ComputeMG`` —
+  four levels rediscretised as HPCG's ``GenerateCoarseProblem`` does,
+  injection transfers, and one 8-colour symmetric Gauss–Seidel step before
+  and after each coarse correction (Pallas kernels,
+  :mod:`repro.kernels.stencil27`).
 
 * **Algebraic** (``precond="amg"``, any COO pattern): smoothed-aggregation
   AMG as a first-class citizen of the plan engine.  The *analyze* half
@@ -40,6 +45,7 @@ import numpy as np
 
 from ..data.poisson import vc_coefficients
 from ..kernels.ref import stencil5_ref
+from ..kernels.stencil27 import OFFSETS, Stencil27Meta, inside
 from .sparse import aggregate_pattern, coo_matvec, spgemm_program
 from .spans import count, span
 
@@ -237,6 +243,101 @@ def make_mg_preconditioner(kappa: jax.Array, **kw):
     """Factory matching the core.precond interface."""
     mg = MultigridPreconditioner(kappa, **kw)
     return lambda r: mg(r)
+
+
+# ---------------------------------------------------------------------------
+# geometric builder, 3-D: HPCG's ComputeMG on 27-point stencil planes
+# ---------------------------------------------------------------------------
+
+HPCG_LEVELS = 4          # HPCG 3.1: three coarsenings by 2 in each dimension
+
+
+def check_grid(meta) -> None:
+    """Refuse a stencil layout the geometric builder cannot coarsen: the
+    2-D builder needs square planes, the 3-D one dimensions divisible by
+    ``2 ** (HPCG_LEVELS - 1)``."""
+    if isinstance(meta, Stencil27Meta):
+        step = 2 ** (HPCG_LEVELS - 1)
+        if any(d % step for d in meta.shape):
+            raise ValueError(
+                f"precond='mg' on a 3-D stencil needs every dimension "
+                f"divisible by {step}, got nx, ny, nz = "
+                f"{meta.nx}, {meta.ny}, {meta.nz}")
+    elif meta.nx != meta.ny:
+        raise ValueError(f"precond='mg' on a 2-D stencil needs square "
+                         f"planes, got {meta.nx} x {meta.ny}")
+
+
+def hpcg_metas(meta: Stencil27Meta) -> Tuple[Stencil27Meta, ...]:
+    """The grids of HPCG's hierarchy, finest first."""
+    metas = [meta]
+    for _ in range(HPCG_LEVELS - 1):
+        metas.append(metas[-1].coarse())
+    return tuple(metas)
+
+
+def hpcg_coarse_planes(coarse: Stencil27Meta, planes: jax.Array) -> jax.Array:
+    """HPCG's ``GenerateCoarseProblem`` from the fine planes: each coarse
+    cell (i, j, k) takes the row of the fine cell (2i, 2j, 2k), and the
+    couplings that leave the coarse grid are zeroed (traced-safe)."""
+    sub = planes[:, ::2, ::2, ::2]
+    return jnp.stack([jnp.where(inside(coarse, *d), sub[p], 0.0)
+                      for p, d in enumerate(OFFSETS)])
+
+
+def hpcg_state(meta: Stencil27Meta, planes: jax.Array) -> tuple:
+    """Array-only hierarchy: the planes of every level, finest first (the
+    given ``planes`` are level 0, shared, not copied)."""
+    with span("setup.mg3d"):
+        levels = [planes]
+        for m in hpcg_metas(meta)[1:]:
+            levels.append(hpcg_coarse_planes(m, levels[-1]))
+        count("mg3d_build")
+        count("mg3d_levels", len(levels))
+        return tuple(levels)
+
+
+def _inject(r):
+    """Injection restriction: the fine values at (2i, 2j, 2k)."""
+    return r[::2, ::2, ::2]
+
+
+def _inject_add(e):
+    """Transpose of :func:`_inject`: ``e`` at the even fine points, zero
+    between (the V-cycle adds it to x)."""
+    return jax.lax.pad(e, jnp.zeros((), e.dtype), [(0, 1, 1)] * 3)
+
+
+def hpcg_hierarchy(meta: Stencil27Meta, state: tuple,
+                   interpret: Optional[bool] = None) -> Tuple[Level, ...]:
+    """:class:`Level` closures of HPCG's V-cycle over the planes of
+    :func:`hpcg_state`: one symmetric Gauss–Seidel (8-colour) before and
+    one after the coarse correction on every level, injection transfers,
+    and one SymGS from zero as the coarsest level's solve.  Level ``l``'s
+    launches are named ``stencil27_l<l>`` and ``symgs27_l<l>``."""
+    from ..kernels.stencil27 import stencil27_pallas, symgs27_pallas
+    levels = []
+    last = len(state) - 1
+    for l, (m, planes) in enumerate(zip(hpcg_metas(meta), state)):
+        mv = functools.partial(stencil27_pallas, m, planes,
+                               name=f"stencil27_l{l}", interpret=interpret)
+        gs = functools.partial(symgs27_pallas, m, planes,
+                               name=f"symgs27_l{l}", interpret=interpret)
+        if l == last:
+            levels.append(Level(matvec=mv, smooth=gs,
+                                coarse_solve=lambda b, gs=gs:
+                                    gs(jnp.zeros_like(b), b)))
+        else:
+            levels.append(Level(matvec=mv, smooth=gs, restrict=_inject,
+                                prolong=_inject_add))
+    return tuple(levels)
+
+
+def hpcg_preconditioner(meta: Stencil27Meta, state: tuple,
+                        interpret: Optional[bool] = None) -> Callable:
+    """One HPCG V-cycle per application, on flat vectors."""
+    levels = hpcg_hierarchy(meta, state, interpret)
+    return lambda r: v_cycle(levels, r.reshape(meta.shape)).reshape(-1)
 
 
 # ---------------------------------------------------------------------------

@@ -179,8 +179,8 @@ class PreconditionerPlan:
                 raise ValueError(
                     "precond='mg' needs a stencil-layout SparseTensor "
                     "(structured-grid operator)")
-            if stencil.nx != stencil.ny:
-                raise ValueError("precond='mg' requires a square grid")
+            from .multigrid import check_grid
+            check_grid(stencil)
         if self.name == "block_jacobi":
             # eager pattern part: diagonal-block membership + scatter targets
             self.nb = -(-self.shape[0] // block)
@@ -230,14 +230,16 @@ class PreconditionerPlan:
             return jnp.where(jnp.abs(d) > 1e-30, 1.0 / d, 1.0)
         return None
 
-    def refresh_state(self, A, matvec: Callable) -> tuple:
+    def refresh_state(self, A, matvec: Callable, val=None) -> tuple:
         """values-dependent stage, ARRAYS ONLY — traced-safe AND vmappable.
 
         Returns a pytree of arrays (no closures), so a whole stacked batch of
         shared-pattern matrices can run ``jax.vmap(refresh_state)`` through
         one trace — the engine half of the serving tentpole.  The apply
         closure is assembled from this state at solve time by
-        :meth:`make_apply` (cheap, no array work)."""
+        :meth:`make_apply` (cheap, no array work).  ``val``: ``A.val`` in
+        the kernel's layout where the caller already holds it so (the 3-D
+        stencil planes), shared rather than laid out again."""
         if self.name == "none":
             return ()
         if self.name == "jacobi":
@@ -256,10 +258,15 @@ class PreconditionerPlan:
             lmin = jnp.maximum(lmin, lmax * 1e-4)
             return (lmin, lmax)
         if self.name == "mg":
-            from .multigrid import MultigridPreconditioner
-            nx, ny = self.stencil.nx, self.stencil.ny
-            v5 = A.val.reshape(5, nx, ny)
-            return MultigridPreconditioner.from_planes(v5).state()
+            from ..kernels.stencil27 import Stencil27Meta
+            from . import multigrid as _mg
+            meta = self.stencil
+            if isinstance(meta, Stencil27Meta):
+                from ..kernels.ops import stencil27_planes
+                planes = stencil27_planes(meta, A.val if val is None else val)
+                return _mg.hpcg_state(meta, planes)
+            v5 = A.val.reshape(5, meta.nx, meta.ny)
+            return _mg.MultigridPreconditioner.from_planes(v5).state()
         if self.name == "ilu":
             from . import direct as _direct
             return (_direct.numeric_factor(self._ilu, A.val),)
@@ -294,8 +301,11 @@ class PreconditionerPlan:
             return chebyshev(matvec, lmin, lmax, degree=self.degree,
                              fused=fused, interpret=interpret)
         if self.name == "mg":
-            from .multigrid import MultigridPreconditioner
-            return MultigridPreconditioner.from_state(state)
+            from ..kernels.stencil27 import Stencil27Meta
+            from . import multigrid as _mg
+            if isinstance(self.stencil, Stencil27Meta):
+                return _mg.hpcg_preconditioner(self.stencil, state, interpret)
+            return _mg.MultigridPreconditioner.from_state(state)
         if self.name == "ilu":
             from . import direct as _direct
             art = self._ilu

@@ -57,6 +57,8 @@ PLAN_STATS: Dict[str, int] = {
     "t_partition": 0,      # distributed Aᵀ partitions built (once per plan)
     "coarsen": 0,          # AMG pattern coarsenings (symbolic, once/pattern)
     "galerkin": 0,         # AMG numeric Galerkin products (once/values array)
+    "mg3d_build": 0,       # 3-D geometric MG hierarchies (once/values array)
+    "mg3d_levels": 0,      # levels of those hierarchies (added per build)
     "kernel_plan": 0,      # BELL conversions run by the analyze-time kernel plan
     "evictions": 0,        # plans dropped by the bounded LRU plan cache
     "jac_color": 0,        # Jacobian pattern colorings (once per SparseNewton)

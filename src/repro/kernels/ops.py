@@ -18,6 +18,7 @@ from ..core.sparse import BellMeta
 from . import ref as _ref
 from .spmv_bell import bell_spmv_pallas
 from .stencil5 import Stencil5Meta, stencil5_pallas
+from .stencil27 import OFFSETS, Stencil27Meta, stencil27_pallas
 
 
 def _interpret() -> bool:
@@ -143,3 +144,59 @@ def stencil5_matvec_ref(meta: Stencil5Meta, val: jax.Array, x: jax.Array) -> jax
     v5 = val.reshape(5, meta.nx, meta.ny)
     x2 = x.reshape(meta.nx, meta.ny)
     return _ref.stencil5_ref(v5, x2).reshape(meta.nx * meta.ny)
+
+
+# ---------------------------------------------------------------------------
+# 27-point stencil (3-D)
+# ---------------------------------------------------------------------------
+
+def stencil27_planes(meta: Stencil27Meta, val: jax.Array) -> jax.Array:
+    """Values as the kernel's (27, nz, ny, nx) planes: flat values are
+    reshaped (a copy on TPU), planes already in that layout pass through."""
+    return val.reshape((27,) + meta.shape)
+
+
+def _shift3(a: jax.Array, d) -> jax.Array:
+    """``a[c + d]`` at each cell ``c`` of an (nz, ny, nx) array, zero where
+    ``c + d`` leaves the grid."""
+    pads, idx = [], []
+    for k, n in zip(d, a.shape):
+        pads.append((max(-k, 0), max(k, 0)))
+        idx.append(slice(max(k, 0), max(k, 0) + n))
+    return jnp.pad(a, pads)[tuple(idx)]
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(0,))
+def stencil27_matvec(meta: Stencil27Meta, val: jax.Array, x: jax.Array
+                     ) -> jax.Array:
+    """``val``: flat (27·n,) signed planes, or the (27, nz, ny, nx) planes a
+    plan's setup holds; ``x``: (n,).  The launch is ``stencil27_l0``: the
+    operator's own (finest) level."""
+    with jax.named_scope("spmv.stencil"):
+        y = stencil27_pallas(meta, stencil27_planes(meta, val),
+                             x.reshape(meta.shape), "stencil27_l0",
+                             _interpret())
+        return y.reshape(meta.n)
+
+
+def _stencil27_fwd(meta, val, x):
+    return stencil27_matvec(meta, val, x), (val, x)
+
+
+def _stencil27_bwd(meta, res, g):
+    val, x = res
+    planes = stencil27_planes(meta, val)
+    x3 = x.reshape(meta.shape)
+    g3 = g.reshape(meta.shape)
+    # ∂/∂x = Aᵀ g: the coupling c → c+d of Aᵀ is A's coupling c+d → c,
+    # the mirror plane read at the neighbour
+    mirror = len(OFFSETS) - 1            # index of -d is 26 - index of d
+    pt = jnp.stack([_shift3(planes[mirror - p], d)
+                    for p, d in enumerate(OFFSETS)])
+    gx = stencil27_pallas(meta, pt, g3, "stencil27_t", _interpret())
+    # ∂/∂val_d[c] = g[c] · x[c + d]
+    gval = jnp.stack([g3 * _shift3(x3, d) for d in OFFSETS])
+    return gval.reshape(val.shape), gx.reshape(-1)
+
+
+stencil27_matvec.defvjp(_stencil27_fwd, _stencil27_bwd)

@@ -23,6 +23,8 @@ from repro.kernels import solve_step as fk
 from repro.kernels import supernode as ksn
 from repro.kernels.spmv_bell import bell_spmv_pallas
 from repro.kernels.stencil5 import Stencil5Meta, stencil5_pallas
+from repro.kernels.stencil27 import (Stencil27Meta, stencil27_pallas,
+                                     symgs27_pallas)
 from test_kernels import _FUSED_SIGS as FUSED_SIGS
 
 F32, I32 = jnp.float32, jnp.int32
@@ -83,6 +85,33 @@ def test_stencil5_compiles(shape):
     meta = Stencil5Meta(nx=4096, ny=4096)
     _compile(lambda v, x: stencil5_pallas(meta, v, x, False),
              shape(5, 4096, 4096), shape(4096, 4096))
+
+
+def _kernel_calls(text):
+    import re
+    return re.findall(r"%([\w.\-]+) = [^\n]*custom_call_target=\"tpu_custom_"
+                      r"call\"", text)
+
+
+# the HPCG cell's finest (256^3) and coarsest (32^3) grids
+@pytest.mark.parametrize("ng", [256, 32])
+def test_stencil27_compiles(shape, ng):
+    meta = Stencil27Meta(ng, ng, ng)
+    text = _compile(lambda P, x: stencil27_pallas(meta, P, x, "stencil27_l0",
+                                                  False),
+                    shape(27, ng, ng, ng), shape(ng, ng, ng))
+    assert all(c.startswith("stencil27_l0.") for c in _kernel_calls(text))
+
+
+@pytest.mark.parametrize("ng", [256, 32])
+def test_symgs27_compiles(shape, ng):
+    meta = Stencil27Meta(ng, ng, ng)
+    text = _compile(lambda P, x, b: symgs27_pallas(meta, P, x, b,
+                                                   "symgs27_l0", False),
+                    shape(27, ng, ng, ng), shape(ng, ng, ng),
+                    shape(ng, ng, ng))
+    calls = _kernel_calls(text)
+    assert len(calls) == 4 and all(c.startswith("symgs27_l0.") for c in calls)
 
 
 @pytest.mark.parametrize("n_rb,k,batch", [
@@ -195,3 +224,30 @@ def _compile_plan_program(shape):
     assert any(re.fullmatch(r"fused_cg_halfstep\.\d+", c) for c in calls), calls
     assert any(re.fullmatch(r"stencil5_pallas\.\d+", c) for c in calls), calls
     assert " while(" in text
+
+
+def test_hpcg_plan_solve_program_compiles(shape, monkeypatch):
+    """The 3-D MG-CG solve program of an HPCG plan (64^3, four levels) with
+    the fused CG steps compiles for a v5e, every level's SpMV and SymGS
+    launches named after their level."""
+    import re
+
+    from repro import sla
+    from repro.core import get_plan, make_config
+    from repro.data.hpcg import hpcg_problem
+    monkeypatch.setattr(fk, "default_interpret", lambda: False)
+    with jax.enable_x64(False), sla.options(fused_step="on"):
+        A = hpcg_problem(64, 64, 64)
+        cfg = make_config(A, method="cg", precond="mg")
+        plan = get_plan(A, cfg)
+        state = plan.setup(A)
+        program, args = plan.backend.solve_program(
+            plan, state, jnp.ones(A.shape[0], F32), None, cfg)
+        abstract = jax.tree_util.tree_map(
+            lambda a: shape(*a.shape, dtype=a.dtype)
+            if isinstance(a, jax.Array) else a, args)
+        text = program.lower(*abstract).compile().as_text()
+    names = {re.sub(r"\.\d+$", "", c) for c in _kernel_calls(text)}
+    assert {f"symgs27_l{k}" for k in range(4)} <= names, names
+    assert {f"stencil27_l{k}" for k in range(3)} <= names, names
+    assert "fused_cg_halfstep" in names
