@@ -99,10 +99,36 @@ def _smooth(v5, x, b, omega: float = 0.8, iters: int = 2):
     return x
 
 
+_LANE_BLOCK = 256
+
+
+def _halve_lanes(x, w_even: float, w_odd: float):
+    """Halve the minor axis: ``w_even·x[..., 2j] + w_odd·x[..., 2j+1]``.
+
+    A stride-2 reshape or slice of the minor axis is a relayout on the TPU
+    (the minor 2 pads to 128 lanes, or the slice gathers across them), so
+    each block of ``k`` lanes is multiplied by a static (k, k/2) matrix
+    holding the two weights instead: ``k = 256`` where the width divides
+    by it, else the whole width.  The dot runs at ``HIGHEST`` precision,
+    since the TPU's default f32 dot rounds its operands to bfloat16; with
+    weights 1 and 0 the result is then the even lanes exactly."""
+    n = x.shape[-1]
+    k = _LANE_BLOCK if n % _LANE_BLOCK == 0 else n
+    lane = (jax.lax.broadcasted_iota(jnp.int32, (k, k // 2), 0)
+            - 2 * jax.lax.broadcasted_iota(jnp.int32, (k, k // 2), 1))
+    w = jnp.where(lane == 0, w_even,
+                  jnp.where(lane == 1, w_odd, 0.0)).astype(x.dtype)
+    y = jnp.einsum("...bk,kj->...bj", x.reshape(*x.shape[:-1], n // k, k), w,
+                   precision=jax.lax.Precision.HIGHEST)
+    return y.reshape(*x.shape[:-1], n // 2)
+
+
 def _restrict(r):
-    """Full-weighting 2×2 restriction (cell-centered)."""
+    """Full-weighting 2×2 restriction (cell-centered): row pairs summed as
+    the two lane halves of ``(ng/2, 2·ng)``, then lane pairs averaged."""
     ng = r.shape[0]
-    return r.reshape(ng // 2, 2, ng // 2, 2).mean(axis=(1, 3))
+    s = r.reshape(ng // 2, 2 * ng)
+    return _halve_lanes(s[:, :ng] + s[:, ng:], 0.25, 0.25)
 
 
 def _prolong(e):
@@ -298,8 +324,10 @@ def hpcg_state(meta: Stencil27Meta, planes: jax.Array) -> tuple:
 
 
 def _inject(r):
-    """Injection restriction: the fine values at (2i, 2j, 2k)."""
-    return r[::2, ::2, ::2]
+    """Injection restriction: the fine values at (2i, 2j, 2k), the planes
+    and rows taken by stride on the major and sublane axes, the lanes by
+    :func:`_halve_lanes`."""
+    return _halve_lanes(r[::2, ::2], 1.0, 0.0)
 
 
 def _inject_add(e):

@@ -133,6 +133,16 @@ def test_coarse_levels_match_generated_coarse_problems(dims):
         assert abs(diff).max() == 0.0          # exact, as above
 
 
+@pytest.mark.parametrize("dims", [(8, 8, 8), (16, 24, 40), (64, 64, 64)])
+def test_inject_is_bit_exact(dims):
+    """Injection keeps the fine values at the even points, bit for bit."""
+    r = jnp.asarray(np.random.default_rng(sum(dims)).standard_normal(dims),
+                    jnp.float32)
+    got = mg._inject(r)
+    assert got.dtype == jnp.float32
+    assert np.array_equal(np.asarray(got), np.asarray(r[::2, ::2, ::2]))
+
+
 @pytest.fixture(scope="module", params=GRIDS,
                 ids=lambda d: "x".join(map(str, d)))
 def vcycle(request):

@@ -114,6 +114,28 @@ def test_symgs27_compiles(shape, ng):
     assert len(calls) == 4 and all(c.startswith("symgs27_l0.") for c in calls)
 
 
+# the MG cells' finest transfers: the 2-D full weighting at 4096^2 and the
+# HPCG injection at 256^3, each fed r - A x as in the V-cycle.  A form that
+# strides the lane axis moves 8.9 and 9.9 GB a call (the 2-D one through a
+# 4.3 GB padded temporary); the lane-dense forms move 0.6 and 0.4 GB.
+@pytest.mark.parametrize("restrict,dims", [
+    ("_restrict", (4096, 4096)),
+    ("_inject", (256, 256, 256)),
+])
+def test_mg_restriction_keeps_lanes_dense(shape, restrict, dims):
+    from repro.core import multigrid
+    fn = getattr(multigrid, restrict)
+    compiled = jax.jit(lambda b, y: fn(b - y)).lower(
+        shape(*dims), shape(*dims)).compile()
+    cost = compiled.cost_analysis()
+    cost = cost[0] if isinstance(cost, list) else cost
+    assert cost["bytes accessed"] < 1e9, cost["bytes accessed"]
+    temp = compiled.memory_analysis().temp_size_in_bytes
+    assert temp < 100e6, temp
+    # the lane compaction is a dot at full f32 precision, not one bf16 pass
+    assert "operand_precision={highest,highest}" in compiled.as_text()
+
+
 @pytest.mark.parametrize("n_rb,k,batch", [
     (32768, 69, None),     # graph phase: 2^18-node geometric graph
     (512, 2, 16),          # serve phase: 64^2 Poisson, vmapped requests
