@@ -11,7 +11,7 @@ the perf claim is carried by the roofline byte model, asserted below):
 * ``spmv/fused_step_model`` — the roofline byte model of one CG iteration:
   ``launch.roofline.assert_fused_step_savings`` raises (→ suite fails, CI
   red) unless the fused step stays under 0.5× the separate-pass baseline
-  and the baseline matches the compiled-HLO measurement.
+  and the baseline matches XLA's byte count for the compiled passes.
 """
 import jax
 import jax.numpy as jnp

@@ -1,6 +1,7 @@
-"""repro — JAX/TPU reproduction of torch-sla (differentiable sparse linear
-algebra with adjoint solvers and sparse tensor parallelism), embedded in a
-multi-pod LM training/serving framework.
+"""repro — JAX/TPU reproduction of torch-sla: differentiable sparse linear
+algebra with adjoint solvers and sparse tensor parallelism.  One plan per
+sparsity pattern serves direct, iterative, multigrid, nonlinear, eigen and
+adjoint solves, on one chip or sharded over a mesh.
 
 The supported public surface is :mod:`repro.sla`::
 

@@ -53,16 +53,13 @@ layout and preconditioner build reused); for non-symmetric patterns a
 transposed sibling plan is analyzed once and cached on the forward plan.
 
 ``PLAN_STATS`` counts analyze/setup/cache events so tests (and profiles) can
-assert reuse; ``register_backend`` adds custom backends either as a
-``Backend`` subclass or as a legacy ``solve(cfg, A, b, x0)`` function.
+assert reuse; ``register_backend`` adds a custom ``Backend`` subclass.
 """
 from __future__ import annotations
 
 import collections
 import copy
 import dataclasses
-import sys
-import types
 import weakref
 from functools import partial
 from typing import Any, Callable, Dict, Optional, Tuple
@@ -81,11 +78,8 @@ from .sparse import (SparseTensor, backward_error, build_bell, coo_matvec,
                      has_full_diagonal)
 
 # The dispatch knobs (dense/direct budgets, BELL fill floor, fused-step mode,
-# plan-cache bounds) live in repro.core.options now — one immutable record
-# behind sla.set_options() / sla.options(...) / REPRO_SLA_* env vars.  The
-# historical module globals (DENSE_BUDGET, DIRECT_BUDGET, BELL_MIN_FILL,
-# FUSED_STEP, PLAN_CACHE_CAP, PLAN_CACHE_BYTES) remain as deprecated
-# read/write aliases — see the module __getattr__ / class swap at the bottom.
+# plan-cache bounds) live in repro.core.options — one immutable record
+# behind sla.set_options() / sla.options(...) / REPRO_SLA_* env vars.
 DEFAULT_MAXITER = 2000
 
 # observable analyze/setup/cache/compile counters, defined beside the span
@@ -893,38 +887,16 @@ class DistBackend(Backend):
         return _dist.dist_transpose_plan(plan)
 
 
-class _FnBackend(Backend):
-    """Adapter for legacy ``register_backend(name, solve_fn, applicable)``."""
-    handles_batch = True
-
-    def __init__(self, name, solve_fn, applicable):
-        self.name = name
-        self._solve_fn = solve_fn
-        self._applicable = applicable
-
-    def applicable(self, A):
-        return self._applicable(A)
-
-    def solve(self, plan, state, A, b, x0, cfg):
-        return self._solve_fn(cfg, A, b, x0)
-
-
 BACKENDS: Dict[str, Backend] = {
     b.name: b for b in (DenseBackend(), DirectBackend(), JnpBackend(),
                         PallasBackend(), StencilBackend(), DistBackend())}
 
 
-def register_backend(name: str, solve_fn: Optional[Callable] = None,
-                     applicable: Optional[Callable] = None, *,
-                     backend: Optional[Backend] = None):
-    """Register a backend: either a :class:`Backend` instance (``backend=``)
-    or the legacy ``(solve_fn, applicable)`` function pair."""
-    if backend is not None:
-        backend.name = name
-        BACKENDS[name] = backend
-    else:
-        BACKENDS[name] = _FnBackend(name, solve_fn,
-                                    applicable or (lambda A: True))
+def register_backend(name: str, backend: Backend):
+    """Register a :class:`Backend` instance under ``name`` (it becomes
+    selectable as ``backend=name``)."""
+    backend.name = name
+    BACKENDS[name] = backend
 
 
 def select_backend(A: SparseTensor, backend: str, method: str):
@@ -1351,45 +1323,3 @@ def solve_impl(cfg: SolverConfig, A: SparseTensor, b: jax.Array,
                x0: Optional[jax.Array] = None):
     """One un-differentiated solve through the cached plan."""
     return get_plan(A, cfg).solve(A, b, x0, cfg=cfg)
-
-
-# ---------------------------------------------------------------------------
-# deprecated knob aliases — the pre-options module globals
-# ---------------------------------------------------------------------------
-
-_DEPRECATED_GLOBALS = {
-    "FUSED_STEP": "fused_step",
-    "DENSE_BUDGET": "dense_budget",
-    "DIRECT_BUDGET": "direct_budget",
-    "BELL_MIN_FILL": "bell_min_fill",
-    "PLAN_CACHE_CAP": "plan_cache_cap",
-    "PLAN_CACHE_BYTES": "plan_cache_bytes",
-}
-
-
-def __getattr__(name: str):
-    """PEP 562 read alias: ``dispatch.FUSED_STEP`` etc. forward to the active
-    :class:`repro.core.options.Options`, warning once per name."""
-    field = _DEPRECATED_GLOBALS.get(name)
-    if field is not None:
-        _options.warn_deprecated_alias(name, field)
-        return getattr(_options.current(), field)
-    raise AttributeError(f"module {__name__!r} has no attribute {name!r}")
-
-
-class _DeprecatedGlobalsModule(types.ModuleType):
-    """Write alias: PEP 562 covers reads only, so assignment to the legacy
-    globals (``dispatch.FUSED_STEP = "on"``) is intercepted by swapping the
-    module's class — the write warns once and forwards to ``set_options``,
-    keeping old scripts working without reintroducing mutable globals."""
-
-    def __setattr__(self, name, value):
-        field = _DEPRECATED_GLOBALS.get(name)
-        if field is not None:
-            _options.warn_deprecated_alias(name, field)
-            _options.set_options(**{field: value})
-            return
-        super().__setattr__(name, value)
-
-
-sys.modules[__name__].__class__ = _DeprecatedGlobalsModule

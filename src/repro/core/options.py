@@ -1,12 +1,8 @@
-"""Typed, scoped engine options — the replacement for the dispatch globals.
+"""Typed, scoped engine options.
 
-Until PR 7 the engine's knobs were mutable module globals on
-:mod:`repro.core.dispatch` (``FUSED_STEP``, ``DENSE_BUDGET``,
-``DIRECT_BUDGET``, ``BELL_MIN_FILL``, ``PLAN_CACHE_CAP``).  Globals are hard
-to scope (a benchmark flipping ``FUSED_STEP`` leaks into the next suite) and
-invisible to the public API.  This module holds ONE immutable
-:class:`Options` record behind three entry points, re-exported by
-:mod:`repro.sla`:
+The engine's knobs (dispatch budgets, the BELL fill floor, the fused-step
+mode, the plan-cache bounds) are ONE immutable :class:`Options` record
+behind three entry points, re-exported by :mod:`repro.sla`:
 
 * :func:`set_options` — process-wide update (``sla.set_options(fused_step="on")``);
 * :func:`options` — context manager for a scoped override
@@ -17,9 +13,7 @@ invisible to the public API.  This module holds ONE immutable
 
 Every internal read goes through :func:`current` at *use* time (budgets at
 dispatch time, ``fused_step`` at solve-trace time, cache bounds at
-insertion), so overrides apply to plans that already exist.  The old module
-globals survive as deprecated read/write aliases on ``repro.core.dispatch``
-that emit a :class:`DeprecationWarning` once per name and forward here.
+insertion), so overrides apply to plans that already exist.
 """
 from __future__ import annotations
 
@@ -27,7 +21,6 @@ import contextlib
 import dataclasses
 import os
 import threading
-import warnings
 from typing import Optional
 
 __all__ = ["Options", "current", "set_options", "options"]
@@ -190,21 +183,3 @@ def options(**kw):
     finally:
         _state.stack.pop()
 
-
-# ---------------------------------------------------------------------------
-# deprecated-alias plumbing (the old dispatch globals)
-# ---------------------------------------------------------------------------
-
-_warned: set = set()
-
-
-def warn_deprecated_alias(old: str, new: str) -> None:
-    """Emit the deprecation warning for a legacy global — once per name."""
-    if old in _warned:
-        return
-    _warned.add(old)
-    warnings.warn(
-        f"repro.core.dispatch.{old} is deprecated; use "
-        f"repro.sla.set_options({new}=...) or the repro.sla.options(...) "
-        f"context manager (env: {ENV_PREFIX}{new.upper()})",
-        DeprecationWarning, stacklevel=3)

@@ -608,7 +608,7 @@ def picard_solve(fixed_point: Callable, x0: jax.Array, *, tol: float = 1e-8,
 
 def anderson_solve(fixed_point: Callable, x0: jax.Array, *, m: int = 5,
                    tol: float = 1e-8, maxiter: int = 200, beta: float = 1.0,
-                   ridge: float = 1e-12, gram_solver: str = "pinv"):
+                   ridge: float = 1e-12):
     """Anderson acceleration, type-II difference form (Walker & Ni 2011):
 
         f_k = G(x_k) − x_k
@@ -620,15 +620,11 @@ def anderson_solve(fixed_point: Callable, x0: jax.Array, *, m: int = 5,
     The normal-equations Gram matrix ΔF ΔFᵀ is structurally rank-deficient
     whenever the window is degenerate — fewer iterations than ``m``
     (zero-padded rows), duplicate residual columns, or a residual space of
-    dimension < m (any affine map).  ``gram_solver="pinv"`` (default) solves
-    it through :func:`eigh_pinv_solve`, the relative-cutoff pseudo-inverse
-    :func:`block_cg` uses for exactly the same breakdown; ``"ridge"`` keeps
-    the legacy fixed-ridge ``solve(G + ridge·I)`` path, which stagnates in
-    f32 (roundoff ~1e-7·‖G‖ swamps the 1e-12 ridge) — retained only as the
-    A/B baseline for the regression test."""
-    if gram_solver not in ("pinv", "ridge"):
-        raise ValueError(f"gram_solver must be 'pinv'|'ridge', "
-                         f"got {gram_solver!r}")
+    dimension < m (any affine map).  It is solved through
+    :func:`eigh_pinv_solve`, the relative-cutoff pseudo-inverse
+    :func:`block_cg` uses for exactly the same breakdown (``ridge`` is its
+    absolute floor); a fixed-ridge ``solve(G + ridge·I)`` stagnates in f32,
+    where roundoff ~1e-7·‖G‖ swamps a 1e-12 ridge."""
     n = x0.shape[-1]
     dtype = x0.dtype
     Xh = jnp.zeros((m + 1, n), dtype)   # iterate history (last row = newest)
@@ -650,11 +646,7 @@ def anderson_solve(fixed_point: Callable, x0: jax.Array, *, m: int = 5,
         valid = (jnp.arange(m) >= (m - mk))[:, None]
         dXv = jnp.where(valid, dX, 0.0)
         dFv = jnp.where(valid, dF, 0.0)
-        if gram_solver == "pinv":
-            gamma = eigh_pinv_solve(dFv @ dFv.T, dFv @ f, ridge=ridge)
-        else:
-            gram = dFv @ dFv.T + ridge * jnp.eye(m, dtype=dtype)
-            gamma = jnp.linalg.solve(gram, dFv @ f)
+        gamma = eigh_pinv_solve(dFv @ dFv.T, dFv @ f, ridge=ridge)
         x_new = x + beta * f - gamma @ (dXv + beta * dFv)
         return (x_new, Xh, Fh, k + 1, rn)
 

@@ -1,238 +1,14 @@
-"""Roofline-term extraction from compiled dry-run artifacts.
+"""Byte model of the fused CG step, checked against XLA's own count.
 
-Three terms per (arch × shape × mesh), in seconds (TPU v5e constants):
-
-    compute    = FLOPs_per_chip / 197e12
-    memory     = bytes_per_chip / 819e9
-    collective = collective_bytes_per_chip / 50e9 (per-link ICI)
-
-XLA facts established by probing (see EXPERIMENTS.md §Roofline methodology):
-``compiled.cost_analysis()`` reports **per-device** numbers for the SPMD
-partitioned module, and counts while/scan bodies **once** (trip counts are
-ignored).  We therefore parse ``compiled.as_text()`` ourselves:
-
-* computations + call graph (fusion ``calls=``, while ``body=/condition=``);
-* ``known_trip_count`` from while backend_config (fallback: the constant
-  compared in the condition computation);
-* per-computation dot FLOPs (2 · |result| · |contracted|, operand shapes from
-  the computation symbol table) × the transitive loop multiplier;
-* per-computation materialized result bytes (fusion internals excluded)
-  × multiplier × 2 (read+write traffic model);
-* collective result bytes × multiplier, by kind.
-
-Elementwise FLOPs outside dots use XLA's own (loop-uncorrected) count as a
-lower bound; dots dominate every assigned architecture.
+``solver_step_traffic`` models the HBM traffic of one preconditioned-CG
+iteration's vector work, fused (``kernels/solve_step.py``) against the
+textbook sequence of separate passes.  ``measured_cg_baseline_bytes``
+compiles that separate-pass sequence and reads XLA's ``bytes accessed``;
+``assert_fused_step_savings`` gates the ratio (``benchmarks/spmv.py``).
 """
 from __future__ import annotations
 
-import dataclasses
-import re
-from typing import Dict, List, Optional, Tuple
-
-from ..configs.base import ModelConfig, ShapeConfig
-from .mesh import HBM_BW, ICI_BW, PEAK_FLOPS_BF16
-
-_DTYPE_BYTES = {
-    "pred": 1, "s8": 1, "u8": 1, "s4": 1, "u4": 1, "s16": 2, "u16": 2,
-    "bf16": 2, "f16": 2, "s32": 4, "u32": 4, "f32": 4, "s64": 8, "u64": 8,
-    "f64": 8, "c64": 8, "c128": 16,
-}
-
-_COLLECTIVES = ("all-gather", "all-reduce", "reduce-scatter", "all-to-all",
-                "collective-permute")
-
-_SHAPE_RE = re.compile(r"(\w+)\[([\d,]*)\]")
-
-
-def _shape_bytes(shape_str: str) -> int:
-    total = 0
-    for m in _SHAPE_RE.finditer(shape_str):
-        dt, dims = m.group(1), m.group(2)
-        if dt not in _DTYPE_BYTES:
-            continue
-        n = 1
-        if dims:
-            for d in dims.split(","):
-                n *= int(d)
-        total += n * _DTYPE_BYTES[dt]
-    return total
-
-
-def _shape_dims(shape_str: str) -> List[int]:
-    m = _SHAPE_RE.search(shape_str)
-    if not m:
-        return []
-    return [int(d) for d in m.group(2).split(",")] if m.group(2) else []
-
-
-@dataclasses.dataclass
-class Comp:
-    name: str
-    lines: List[str]
-    shapes: Dict[str, str]                  # %instr → result shape str
-    dots_flops: float = 0.0
-    result_bytes: int = 0
-    colls: Dict[str, int] = dataclasses.field(default_factory=dict)
-    whiles: List[Tuple[str, str, int]] = dataclasses.field(default_factory=list)
-    fusion_calls: List[str] = dataclasses.field(default_factory=list)
-
-
-_INSTR_RE = re.compile(
-    r"^\s*(?:ROOT\s+)?%([\w\.\-]+)\s*=\s*((?:\([^()]*\))|(?:\w+\[[\d,]*\]"
-    r"(?:\{[^}]*\})?))\s*([\w\-]+)")
-
-
-def parse_hlo(text: str) -> Dict[str, "Comp"]:
-    comps: Dict[str, Comp] = {}
-    entry: Optional[str] = None
-    cur: Optional[Comp] = None
-    for raw in text.splitlines():
-        header = re.match(r"^(ENTRY\s+)?%?([\w\.\-]+)\s*\(.*\)\s*->.*\{",
-                          raw)
-        if header and not raw.lstrip().startswith("%param"):
-            cur = Comp(header.group(2), [], {})
-            comps[cur.name] = cur
-            if header.group(1):
-                entry = cur.name
-            continue
-        if cur is None:
-            continue
-        cur.lines.append(raw)
-        m = _INSTR_RE.match(raw)
-        if not m:
-            # parameters: "%p = f32[..] parameter(0)" matches; skip otherwise
-            continue
-        iname, shape_str, op = m.group(1), m.group(2), m.group(3)
-        cur.shapes[iname] = shape_str
-        if op == "dot":
-            cur.dots_flops += _dot_flops(raw, shape_str, cur.shapes)
-        elif op == "convolution":
-            cur.dots_flops += _conv_flops(raw, shape_str, cur.shapes)
-        elif op == "while":
-            body = _attr(raw, "body")
-            cond = _attr(raw, "condition")
-            trip = _trip_from_config(raw)
-            cur.whiles.append((body, cond, trip or 0))
-        elif op == "fusion":
-            callee = _attr(raw, "calls")
-            if callee:
-                cur.fusion_calls.append(callee)
-        elif op in _COLLECTIVES or op.rstrip("-start") in _COLLECTIVES:
-            kind = next(k for k in _COLLECTIVES if op.startswith(k))
-            cur.colls[kind] = cur.colls.get(kind, 0) + _shape_bytes(shape_str)
-        if op not in ("parameter", "constant", "get-tuple-element", "tuple",
-                      "bitcast", "copy"):
-            cur.result_bytes += _shape_bytes(shape_str)
-    comps["__entry__"] = comps.get(entry, next(iter(comps.values())))
-    return comps
-
-
-def _attr(line: str, key: str) -> Optional[str]:
-    m = re.search(key + r"=%?([\w\.\-]+)", line)
-    return m.group(1) if m else None
-
-
-def _trip_from_config(line: str) -> Optional[int]:
-    m = re.search(r'known_trip_count[^0-9]*(\d+)', line)
-    return int(m.group(1)) if m else None
-
-
-def _dot_flops(line: str, result_shape: str, shapes: Dict[str, str]) -> float:
-    res = _shape_dims(result_shape)
-    out = 1.0
-    for d in res:
-        out *= d
-    m = re.search(r"dot\(%?([\w\.\-]+)", line)
-    lhs = shapes.get(m.group(1)) if m else None
-    cdims = re.search(r"lhs_contracting_dims=\{([\d,]*)\}", line)
-    contracted = 1.0
-    if lhs and cdims and cdims.group(1):
-        ldims = _shape_dims(lhs)
-        for d in cdims.group(1).split(","):
-            i = int(d)
-            if i < len(ldims):
-                contracted *= ldims[i]
-    return 2.0 * out * contracted
-
-
-def _conv_flops(line: str, result_shape: str, shapes: Dict[str, str]) -> float:
-    res = _shape_dims(result_shape)
-    out = 1.0
-    for d in res:
-        out *= d
-    m = re.search(r"convolution\(%?([\w\.\-]+),\s*%?([\w\.\-]+)", line)
-    rhs = shapes.get(m.group(2)) if m else None
-    k = 1.0
-    if rhs:
-        rdims = _shape_dims(rhs)
-        for d in rdims[:-1]:        # all but output-feature (approximation)
-            k *= d
-    return 2.0 * out * k
-
-
-def _multipliers(comps: Dict[str, Comp]) -> Tuple[Dict[str, float], set]:
-    """Transitive loop multiplier per computation + the set of computations
-    whose instruction results are materialized (fusion internals excluded)."""
-    entry = comps["__entry__"].name
-    mult: Dict[str, float] = {}
-    materialized = set()
-
-    def visit(name: str, m: float, mat: bool):
-        if name not in comps:
-            return
-        mult[name] = mult.get(name, 0.0) + m
-        if mat:
-            materialized.add(name)
-        c = comps[name]
-        for body, cond, trip in c.whiles:
-            t = max(trip, 1)
-            if body:
-                visit(body, m * t, mat)
-            if cond:
-                visit(cond, m * t, False)
-        for callee in c.fusion_calls:
-            visit(callee, m, False)
-
-    visit(entry, 1.0, True)
-    return mult, materialized
-
-
-@dataclasses.dataclass
-class HloStats:
-    dot_flops: float
-    traffic_bytes: float
-    collective_bytes: float
-    coll_by_kind: Dict[str, float]
-    n_collectives: int
-
-
-def analyze_hlo(text: str) -> HloStats:
-    comps = parse_hlo(text)
-    mult, materialized = _multipliers(comps)
-    dot_flops = 0.0
-    traffic = 0.0
-    coll: Dict[str, float] = {}
-    n_coll = 0
-    for name, c in comps.items():
-        if name == "__entry__":
-            continue
-        m = mult.get(name, 0.0)
-        if m == 0.0:
-            continue
-        dot_flops += m * c.dots_flops
-        if name in materialized:
-            traffic += m * c.result_bytes * 2.0      # write + read model
-        for kind, b in c.colls.items():
-            coll[kind] = coll.get(kind, 0.0) + m * b
-            n_coll += 1
-    return HloStats(dot_flops=dot_flops, traffic_bytes=traffic,
-                    collective_bytes=sum(coll.values()), coll_by_kind=coll,
-                    n_collectives=n_coll)
-
-
-# ---------------------------------------------------------------------------
-# solver-step traffic model (kernel plans — kernels/solve_step.py)
-# ---------------------------------------------------------------------------
+from typing import Dict
 
 # HBM traffic of ONE textbook preconditioned-CG iteration's vector work, in
 # vector-lengths (matvec excluded — identical on both sides).  Each pass
@@ -276,10 +52,11 @@ def solver_step_traffic(n: int, itemsize: int = 8) -> dict:
 
 
 def measured_cg_baseline_bytes(n: int, dtype: str = "float64") -> float:
-    """Compile the UNFUSED pass sequence and count its HLO traffic — the
-    ground truth the model above is checked against (the fused side cannot
-    be measured the same way off-TPU: interpret-mode Pallas lowers to a
-    scan emulation whose HLO byte counts are meaningless)."""
+    """Compile the UNFUSED pass sequence and return XLA's ``bytes accessed``
+    for it — the ground truth the model above is checked against (the fused
+    side cannot be measured the same way off-TPU: interpret-mode Pallas
+    lowers to a scan emulation whose byte counts are meaningless).  The
+    module has no loop, so XLA's count needs no trip-count correction."""
     import jax
     import jax.numpy as jnp
 
@@ -297,8 +74,8 @@ def measured_cg_baseline_bytes(n: int, dtype: str = "float64") -> float:
 
     vec = jax.ShapeDtypeStruct((n,), dtype)
     sca = jax.ShapeDtypeStruct((), dtype)
-    txt = jax.jit(step).lower(vec, vec, vec, vec, vec, sca).compile().as_text()
-    return analyze_hlo(txt).traffic_bytes
+    compiled = jax.jit(step).lower(vec, vec, vec, vec, vec, sca).compile()
+    return float(compiled.cost_analysis()["bytes accessed"])
 
 
 def assert_fused_step_savings(n: int = 65536, threshold: float = 0.5,
@@ -322,60 +99,5 @@ def assert_fused_step_savings(n: int = 65536, threshold: float = 0.5,
     if not measured >= floor:
         raise AssertionError(
             f"measured unfused-baseline traffic {measured:.0f} below "
-            f"plausibility floor {floor} — HLO parse drifted?")
+            f"plausibility floor {floor} — cost analysis drifted?")
     return model
-
-
-# ---------------------------------------------------------------------------
-# MODEL_FLOPS (6·N·D analytic)
-# ---------------------------------------------------------------------------
-
-def active_params(cfg: ModelConfig) -> int:
-    total = cfg.param_count()
-    if cfg.n_experts:
-        expert_p = 0
-        for kind in cfg.pattern_layers:
-            if kind == "moe":
-                expert_p += cfg.n_experts * 3 * cfg.d_model * cfg.d_ff_expert
-        active_expert = expert_p * cfg.top_k // cfg.n_experts
-        return total - expert_p + active_expert
-    return total
-
-
-def model_flops(cfg: ModelConfig, shape: ShapeConfig) -> float:
-    """Global useful FLOPs: 6·N·D train, 2·N·D prefill, 2·N·B decode."""
-    n = active_params(cfg)
-    if shape.kind == "train":
-        return 6.0 * n * shape.seq_len * shape.global_batch
-    if shape.kind == "prefill":
-        return 2.0 * n * shape.seq_len * shape.global_batch
-    return 2.0 * n * shape.global_batch
-
-
-# ---------------------------------------------------------------------------
-# the three terms (per-chip seconds)
-# ---------------------------------------------------------------------------
-
-def roofline_terms(cost: dict, hlo: HloStats, chips: int) -> dict:
-    """``cost`` is XLA's per-device cost_analysis dict; ``hlo`` our corrected
-    text analysis (also per-device — the module is partitioned)."""
-    xla_flops = float(cost.get("flops", 0.0))
-    xla_bytes = float(cost.get("bytes accessed", 0.0))
-    flops = max(hlo.dot_flops, xla_flops)
-    bytes_ = max(hlo.traffic_bytes, xla_bytes)
-    return {
-        "hlo_flops_per_chip": flops,
-        "hlo_bytes_per_chip": bytes_,
-        "xla_flops_raw": xla_flops,
-        "xla_bytes_raw": xla_bytes,
-        "collective_bytes_per_chip": hlo.collective_bytes,
-        "t_compute_s": flops / PEAK_FLOPS_BF16,
-        "t_memory_s": bytes_ / HBM_BW,
-        "t_collective_s": hlo.collective_bytes / ICI_BW,
-    }
-
-
-def dominant_term(terms: dict) -> str:
-    t = {"compute": terms["t_compute_s"], "memory": terms["t_memory_s"],
-         "collective": terms["t_collective_s"]}
-    return max(t, key=t.get)

@@ -2,9 +2,8 @@
 
 Production traffic means thousands of concurrent solves on a handful of
 sparsity patterns — exactly the amortization the plan engine was built for.
-This driver (modeled on :mod:`repro.launch.serve`'s batched-request loop)
-turns a stream of independent ``(A, b)`` requests into grouped, vmapped
-dispatches:
+This driver turns a stream of independent ``(A, b)`` requests into grouped,
+vmapped dispatches:
 
 1. **group** incoming requests by plan key — shared pattern (the tensors'
    plan-cache identity) + resolved :class:`SolverConfig`, so every request

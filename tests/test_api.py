@@ -1,12 +1,9 @@
-"""Public API surface (repro.sla), options API, and deprecated aliases.
+"""Public API surface (repro.sla), options API, and typed results.
 
 The surface snapshot is the contract: adding or removing a public name must
 be a deliberate edit to EXPECTED_SURFACE here (and to docs/api.md via
-tools/gen_api_ref.py), never an accident.  These are also the ONLY tests
-allowed to touch the deprecated dispatch globals.
+tools/gen_api_ref.py), never an accident.
 """
-import warnings
-
 import jax.numpy as jnp
 import numpy as np
 import pytest
@@ -128,55 +125,25 @@ def test_options_read_at_use_time():
         assert dispatch.select_backend(A, "auto", "auto")[0] == "jnp"
 
 
-# ---------------------------------------------------------------------------
-# deprecated aliases (the ONLY tests that may touch them)
-# ---------------------------------------------------------------------------
+def test_register_backend_takes_a_backend_instance():
+    class CountingDense(dispatch.DenseBackend):
+        calls = 0
 
-@pytest.fixture()
-def _fresh_warn_state():
-    saved = set(_options._warned)
-    _options._warned.clear()
-    yield
-    _options._warned.clear()
-    _options._warned.update(saved)
+        def solve(self, plan, dense, A, b, x0, cfg):
+            CountingDense.calls += 1
+            return super().solve(plan, dense, A, b, x0, cfg)
 
-
-def test_deprecated_global_read_warns_once(_fresh_warn_state):
-    with warnings.catch_warnings(record=True) as w:
-        warnings.simplefilter("always")
-        v = dispatch.DIRECT_BUDGET
-        assert v == sla.get_options().direct_budget
-        _ = dispatch.DIRECT_BUDGET
-    deps = [x for x in w if issubclass(x.category, DeprecationWarning)]
-    assert len(deps) == 1, [str(x.message) for x in w]
-    assert "direct_budget" in str(deps[0].message)
-
-
-def test_deprecated_global_write_warns_and_forwards(_fresh_warn_state):
-    base = sla.get_options().fused_step
-    with warnings.catch_warnings(record=True) as w:
-        warnings.simplefilter("always")
-        dispatch.FUSED_STEP = "off"
+    A = poisson2d(6)
+    b = jnp.ones(A.shape[0])
+    sla.register_backend("counting_dense", CountingDense())
     try:
-        assert sla.get_options().fused_step == "off"
-        assert dispatch.FUSED_STEP == "off"
+        assert dispatch.BACKENDS["counting_dense"].name == "counting_dense"
+        x = sla.solve(A, b, backend="counting_dense")
     finally:
-        sla.set_options(fused_step=base)
-    deps = [x for x in w if issubclass(x.category, DeprecationWarning)]
-    assert len(deps) == 1
-    assert "fused_step" in str(deps[0].message)
-
-
-def test_new_plan_cache_bytes_alias(_fresh_warn_state):
-    base = sla.get_options().plan_cache_bytes
-    with warnings.catch_warnings(record=True) as w:
-        warnings.simplefilter("always")
-        dispatch.PLAN_CACHE_BYTES = 12345
-    try:
-        assert sla.get_options().plan_cache_bytes == 12345
-    finally:
-        sla.set_options(plan_cache_bytes=base)
-    assert any(issubclass(x.category, DeprecationWarning) for x in w)
+        del dispatch.BACKENDS["counting_dense"]
+    assert CountingDense.calls == 1
+    np.testing.assert_allclose(np.asarray(x), np.asarray(sla.solve(A, b)),
+                               rtol=1e-10)
 
 
 def test_unknown_dispatch_attribute_still_raises():

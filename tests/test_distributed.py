@@ -109,7 +109,7 @@ def test_distributed_gradients_match_single_device():
     assert float(out.split("GB")[1]) < 1e-9
 
 
-def test_pipelined_cg_and_compressed_halo():
+def test_pipelined_cg():
     out = run_forced(PREAMBLE + textwrap.dedent("""
         xp = D.gather_global(D.solve(bs, tol=1e-11, maxiter=4000,
                                      pipelined=True))
@@ -121,29 +121,10 @@ def test_pipelined_cg_and_compressed_halo():
         xp = D.gather_global(xp)
         res = np.abs(np.asarray(As @ jnp.asarray(xp)) - b).max()
         print("PIPE10", res, bool(info.converged))
-
-        # compressed halo exchange: int8 payload, own values exact
-        from jax.sharding import PartitionSpec as P
-        from functools import partial
-        from repro.optim.compress import compressed_halo_exchange
-        @partial(jax.shard_map, mesh=mesh, in_specs=P("data"), out_specs=P("data"),
-                 check_vma=False)
-        def Hq(x):
-            return compressed_halo_exchange(x, 1, 1, "data")
-        @partial(jax.shard_map, mesh=mesh, in_specs=P("data"), out_specs=P("data"),
-                 check_vma=False)
-        def H(x):
-            return halo_exchange(x, 1, 1, "data")
-        x = jnp.asarray(np.random.default_rng(3).normal(size=n))
-        err = jnp.abs(Hq(x) - H(x))
-        print("CQ", float(jnp.max(err)), float(jnp.max(jnp.abs(x))))
     """))
     assert float(out.split("PIPE")[1].split()[0]) < 1e-7
     res10, converged10 = out.split("PIPE10")[1].split()[:2]
     assert float(res10) < 1e-7 and converged10 == "True"
-    parts = out.split("CQ")[1].split()
-    err, scale = float(parts[0]), float(parts[1])
-    assert err <= scale / 127.0 + 1e-9     # int8 quantization bound
 
 
 def test_distributed_eigsh():
