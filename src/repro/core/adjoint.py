@@ -14,6 +14,7 @@ Krylov iterates are never referenced (paper Table 2).
 """
 from __future__ import annotations
 
+import math
 from functools import partial
 from typing import Callable, Optional
 
@@ -23,7 +24,9 @@ import jax.numpy as jnp
 from . import dispatch as _dispatch
 from . import options as _options
 from . import solvers as _solvers
+from ..kernels import ops as _kops
 from .dispatch import SolverConfig
+from .spans import count
 from .sparse import SparseTensor
 
 __all__ = ["sparse_solve", "dist_sparse_solve", "nonlinear_solve",
@@ -44,6 +47,17 @@ def _sum_to_shape(x: jax.Array, shape) -> jax.Array:
 # linear solve (paper §3.2.2 "Linear systems")
 # ---------------------------------------------------------------------------
 
+def _has_plane_layout(meta, val: jax.Array, n: int) -> bool:
+    """True when ``val`` holds a stencil layout's planes, flat: the matrix
+    gradient can then be formed plane by plane.  ``meta`` is static pytree
+    data, so this holds under jit where the pattern arrays are tracers."""
+    layout = _kops.stencil_offsets(meta)
+    if layout is None:
+        return False
+    offsets, grid = layout
+    return math.prod(grid) == n and val.shape[-1:] == (len(offsets) * n,)
+
+
 def sparse_solve(cfg: SolverConfig, A: SparseTensor, b: jax.Array,
                  x0: Optional[jax.Array] = None) -> jax.Array:
     """Differentiable A.solve(b).  ``cfg`` must already be resolved.
@@ -56,9 +70,20 @@ def sparse_solve(cfg: SolverConfig, A: SparseTensor, b: jax.Array,
     sweeps on the FORWARD numeric factors (the per-values setup memo is
     shared, so the backward refactorizes nothing); a once-analyzed transposed
     sibling otherwise.  No re-dispatch, no re-analysis per call.
+
+    The matrix gradient ∂L/∂A_ij = −λ_i x_j is formed on the pattern.  A
+    tensor made with ``stencil=`` (a ``Stencil5Meta`` or ``Stencil27Meta``)
+    promises the stencil layout: values plane-major, slot ``p·n + cell``,
+    planes in ``vc_pattern`` / ``stencil27_pattern`` offset order; ``row``
+    of a slot is its cell and ``col`` the neighbour at the plane's offset,
+    clamped into the grid where it leaves it (a structurally-zero value).
+    On that layout the gradient is formed plane by plane, with no gather
+    (``kernels.ops.stencil_matrix_grad``, counted as
+    ``adjoint_plane_grad``); every other layout gathers over (row, col).
     """
     plan = _dispatch.get_plan(A, cfg)
     row, col = plan.row, plan.col
+    plane_grad = _has_plane_layout(plan.stencil, A.val, plan.shape[0])
 
     @jax.custom_vjp
     def solve_fn(val, rhs):
@@ -78,8 +103,13 @@ def sparse_solve(cfg: SolverConfig, A: SparseTensor, b: jax.Array,
         # the forward factorization here instead of re-running setup.
         tplan = plan.transpose()
         lam, _ = tplan.solve(tplan.matrix(val), g, None, cfg=tplan.adapt(cfg))
-        # ∂L/∂A_ij = −λ_i x_j  on the sparsity pattern — O(nnz)
-        gval_full = -(lam[..., row] * x[..., col])
+        # ∂L/∂A_ij = −λ_i x_j  on the sparsity pattern — O(nnz): plane by
+        # plane on a stencil layout, a gather over (row, col) otherwise
+        if plane_grad:
+            count("adjoint_plane_grad")
+            gval_full = _kops.stencil_matrix_grad(plan.stencil, lam, x)
+        else:
+            gval_full = -(lam[..., row] * x[..., col])
         gval = _sum_to_shape(gval_full, val.shape)
         gb = _sum_to_shape(lam, b.shape)
         return gval, gb

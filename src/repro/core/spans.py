@@ -54,6 +54,7 @@ PLAN_STATS: Dict[str, int] = {
     "cache_hit": 0,        # plan served from a SparseTensor's plan cache
     "cache_miss": 0,       # plan analyzed fresh
     "transpose_shared": 0,  # adjoint reused the forward plan (or its factors)
+    "adjoint_plane_grad": 0,  # adjoint matrix gradients formed plane-wise
     "t_partition": 0,      # distributed Aᵀ partitions built (once per plan)
     "coarsen": 0,          # AMG pattern coarsenings (symbolic, once/pattern)
     "galerkin": 0,         # AMG numeric Galerkin products (once/values array)

@@ -15,7 +15,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..core.sparse import SparseTensor
-from ..kernels.stencil5 import Stencil5Meta
+from ..kernels.stencil5 import OFFSETS, Stencil5Meta
 
 
 def poisson1d(n: int, dtype=np.float64) -> SparseTensor:
@@ -58,8 +58,7 @@ def vc_pattern(ng: int) -> Tuple[np.ndarray, np.ndarray, Stencil5Meta]:
     a clamped in-range column) so COO and stencil layouts share one ``val``."""
     idx = np.arange(ng * ng).reshape(ng, ng)
     rows, cols = [], []
-    offs = [(0, 0), (-1, 0), (1, 0), (0, -1), (0, 1)]
-    for (di, dj) in offs:
+    for (di, dj) in OFFSETS:
         r = idx
         ii = np.clip(np.arange(ng)[:, None] + di, 0, ng - 1)
         jj = np.clip(np.arange(ng)[None, :] + dj, 0, ng - 1)

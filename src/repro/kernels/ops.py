@@ -17,6 +17,7 @@ import jax.numpy as jnp
 from ..core.sparse import BellMeta
 from . import ref as _ref
 from .spmv_bell import bell_spmv_pallas
+from .stencil5 import OFFSETS as STENCIL5_OFFSETS
 from .stencil5 import Stencil5Meta, stencil5_pallas
 from .stencil27 import OFFSETS, Stencil27Meta, stencil27_pallas
 
@@ -128,7 +129,8 @@ def _stencil_bwd(meta, res, g):
     # ∂/∂x = Aᵀ g — reuse the kernel with transposed planes
     vt = _stencil_transpose_planes(v5)
     gx = stencil5_pallas(meta, vt, g2, _interpret()).reshape(-1)
-    # ∂/∂val_d[i,j] = g[i,j] · x[i+off_d, j+off_d]
+    # ∂/∂val_d[i,j] = g[i,j] · x[i+off_d, j+off_d], zero-padded: the kernel
+    # reads zero outside the grid (the adjoint's stencil_matrix_grad clamps)
     xn = jnp.pad(x2, ((1, 0), (0, 0)))[:-1, :]
     xs = jnp.pad(x2, ((0, 1), (0, 0)))[1:, :]
     xw = jnp.pad(x2, ((0, 0), (1, 0)))[:, :-1]
@@ -158,7 +160,8 @@ def stencil27_planes(meta: Stencil27Meta, val: jax.Array) -> jax.Array:
 
 def _shift3(a: jax.Array, d) -> jax.Array:
     """``a[c + d]`` at each cell ``c`` of an (nz, ny, nx) array, zero where
-    ``c + d`` leaves the grid."""
+    ``c + d`` leaves the grid (the kernel never reads those slots; the
+    adjoint's :func:`stencil_matrix_grad` clamps instead)."""
     pads, idx = [], []
     for k, n in zip(d, a.shape):
         pads.append((max(-k, 0), max(k, 0)))
@@ -200,3 +203,49 @@ def _stencil27_bwd(meta, res, g):
 
 
 stencil27_matvec.defvjp(_stencil27_fwd, _stencil27_bwd)
+
+
+# ---------------------------------------------------------------------------
+# matrix gradient of a stencil-layout solve
+# ---------------------------------------------------------------------------
+
+def stencil_offsets(meta):
+    """``(offsets, grid)`` of a stencil layout: the plane offsets in value
+    order and the grid shape the cells are laid out in row-major; ``None``
+    for a meta that is no stencil layout."""
+    if isinstance(meta, Stencil27Meta):
+        return OFFSETS, meta.shape
+    if isinstance(meta, Stencil5Meta):
+        return STENCIL5_OFFSETS, (meta.nx, meta.ny)
+    return None
+
+
+def _edge_shift(a: jax.Array, d) -> jax.Array:
+    """``a[clamp(c + d)]`` over the trailing ``len(d)`` axes: the neighbour
+    at offset ``d``, clamped into the grid (edge-replicated)."""
+    lead = a.ndim - len(d)
+    pads, idx = [(0, 0)] * lead, [slice(None)] * lead
+    for k, n in zip(d, a.shape[lead:]):
+        pads.append((max(-k, 0), max(k, 0)))
+        idx.append(slice(max(k, 0), max(k, 0) + n))
+    return jnp.pad(a, pads, mode="edge")[tuple(idx)]
+
+
+def stencil_matrix_grad(meta, lam: jax.Array, x: jax.Array) -> jax.Array:
+    """∂L/∂A_ij = −λ_i·x_j on a stencil layout's slots, plane by plane.
+
+    ``lam`` and ``x``: ``(..., n)``.  Returns ``(..., planes·n)`` in value
+    order, equal to ``-(lam[..., row] * x[..., col])`` over the layout's
+    pattern (``vc_pattern`` / ``stencil27_pattern``) with no gather.  The
+    pattern clamps an out-of-grid neighbour's column into the grid, so the
+    shift here clamps too: a structurally-zero slot gets the same value as
+    the COO formula.  The kernel VJPs above zero-pad instead: the kernel
+    reads a zero neighbour outside the grid, so its own derivative there
+    is zero."""
+    offsets, grid = stencil_offsets(meta)
+    lead = lam.shape[:-1]
+    l = lam.reshape(lead + grid)
+    xg = x.reshape(x.shape[:-1] + grid)
+    g = jnp.stack([-(l * _edge_shift(xg, d)) for d in offsets],
+                  axis=len(lead))
+    return g.reshape(lead + (len(offsets) * lam.shape[-1],))

@@ -29,6 +29,10 @@ from jax.experimental import pallas as pl
 
 from .solve_step import I0, check_kernel_dtype, default_interpret
 
+# (di, dj) of the coefficient planes C, N, S, W, E over an (nx, ny) grid,
+# in value order
+OFFSETS = ((0, 0), (-1, 0), (1, 0), (0, -1), (0, 1))
+
 
 @dataclasses.dataclass(frozen=True)
 class Stencil5Meta:

@@ -9,6 +9,16 @@ import pytest
 
 jax.config.update("jax_enable_x64", True)
 
+
+@pytest.fixture(autouse=True)
+def _x64_on():
+    """Each test starts with x64 on: a file run earlier in the same worker
+    (the benchmark harness's tests run ``bench/run.py`` in-process, which
+    turns x64 off) must not leave these tests in float32."""
+    if not jax.config.jax_enable_x64:
+        jax.config.update("jax_enable_x64", True)
+
+
 # Every live XLA:CPU executable holds ~50 anonymous memory mappings (LLVM
 # ORC JIT code/data sections).  The full suite compiles enough distinct
 # programs in one process to cross the kernel's vm.max_map_count (65530 by

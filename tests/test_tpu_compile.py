@@ -11,6 +11,7 @@ The topology is described inside a module-scoped fixture, never at import:
 only one process may load the TPU library, and under pytest-xdist each
 worker imports this file.
 """
+import math
 import os
 
 import jax
@@ -134,6 +135,27 @@ def test_mg_restriction_keeps_lanes_dense(shape, restrict, dims):
     assert temp < 100e6, temp
     # the lane compaction is a dot at full f32 precision, not one bf16 pass
     assert "operand_precision={highest,highest}" in compiled.as_text()
+
+
+# the adjoint's per-entry gradient −λ_i·x_j on the gradient cell's 4096^2
+# layout and HPCG's 256^3 one.  The gather form −λ[row]·x[col] compiles to
+# two planes·n gathers (691 and 3,731 GB by XLA's count, run at ~1e8
+# elements/s); the plane form has no gather and moves ≤ 6.2× the least
+# bytes (λ and x in, the planes out).
+@pytest.mark.parametrize("meta", [Stencil5Meta(nx=4096, ny=4096),
+                                  Stencil27Meta(256, 256, 256)],
+                         ids=["stencil5", "stencil27"])
+def test_stencil_matrix_grad_has_no_gather(shape, meta):
+    from repro.kernels.ops import stencil_matrix_grad, stencil_offsets
+    offsets, grid = stencil_offsets(meta)
+    n = math.prod(grid)
+    compiled = jax.jit(lambda l, x: stencil_matrix_grad(meta, l, x)).lower(
+        shape(n), shape(n)).compile()
+    assert " gather(" not in compiled.as_text()
+    cost = compiled.cost_analysis()
+    cost = cost[0] if isinstance(cost, list) else cost
+    least = (len(offsets) + 2) * n * 4
+    assert cost["bytes accessed"] < 8 * least, cost["bytes accessed"]
 
 
 @pytest.mark.parametrize("n_rb,k,batch", [
